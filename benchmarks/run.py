@@ -46,6 +46,8 @@ def main() -> None:
                    help="cost models + kernels only (no training runs)")
     args = p.parse_args()
 
+    from repro.launch.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
     from benchmarks import paper_tables as T
     T.table1_client_cost()
     T.fig3_comm_overhead()

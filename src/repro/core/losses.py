@@ -22,11 +22,12 @@ def chunked_softmax_xent(h, w, labels, valid=None, chunk: int = 512,
     """
     if impl == "pallas":
         from repro.kernels import ops as kops
-        # vocab tile scales with V so h is re-swept at most V/4096 times
-        # per pass ([chunk, 4096] f32 w-tile = 4 MB VMEM)
-        losses = kops.softmax_xent_tokens(h, w, labels.astype(jnp.int32),
-                                          block_t=min(chunk, h.shape[0]),
-                                          block_v=min(4096, w.shape[1]))
+        # w in the activation dtype, as the jnp path computes; the kernel's
+        # default 512-wide vocab tile keeps the double-buffered [D, 512] w
+        # and [chunk, D] h tiles inside VMEM at D = 3072
+        losses = kops.softmax_xent_tokens(h, w.astype(h.dtype),
+                                          labels.astype(jnp.int32),
+                                          block_t=min(chunk, h.shape[0]))
         if valid is not None:
             losses = losses * valid.astype(jnp.float32)
         return losses

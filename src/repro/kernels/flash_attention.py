@@ -50,13 +50,21 @@ NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
 def _block_mask(qp, kp, kv, causal: bool, window: int):
-    """[bq, bk] validity from absolute positions + key-validity bits."""
-    ok = kv[None, :]
+    """[bq, bk] validity from absolute positions + key-validity bits.
+
+    qp is a [bq, 1] column, kp / kv are [1, bk] rows."""
+    ok = kv != 0
     if causal:
-        ok &= kp[None, :] <= qp[:, None]
+        ok = ok & (kp <= qp)
     if window > 0:
-        ok &= (qp[:, None] - kp[None, :]) < window
+        ok = ok & ((qp - kp) < window)
     return ok
+
+
+def _col(row_ref):
+    """A [1, n] row block as an [n, 1] column (per-query-row statistics
+    live as rows in HBM, which keeps their blocks lane-dense)."""
+    return row_ref[...].reshape(1, -1).T
 
 
 # ---------------------------------------------------------------------------
@@ -75,12 +83,12 @@ def _fwd_kernel(qpos_ref, kpos_ref, kvalid_ref, q_ref, k_ref, v_ref,  # in
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, :, 0, :]                     # [bq, hd]
-    k = k_ref[0, :, 0, :]                     # [bk, hd]
-    v = v_ref[0, :, 0, :]                     # [bk, hd]
-    qp = qpos_ref[0, :]                       # [bq] int32
-    kp = kpos_ref[0, :]                       # [bk] int32
-    kv = kvalid_ref[0, :]                     # [bk] bool
+    q = q_ref[0, 0]                           # [bq, hd]
+    k = k_ref[0, 0]                           # [bk, hd]
+    v = v_ref[0, 0]                           # [bk, hd]
+    qp = _col(qpos_ref)                       # [bq, 1] int32
+    kp = kpos_ref[0]                          # [1, bk] int32
+    kv = kvalid_ref[0]                        # [1, bk] int32
 
     s = jax.lax.dot_general(
         q.astype(jnp.float32) * scale, k.astype(jnp.float32),
@@ -88,26 +96,26 @@ def _fwd_kernel(qpos_ref, kpos_ref, kvalid_ref, q_ref, k_ref, v_ref,  # in
     ok = _block_mask(qp, kp, kv, causal, window)
     s_masked = jnp.where(ok, s, NEG_INF)
 
-    m_prev = m_ref[...]
+    m_prev = m_ref[...]                       # [bq, 1]
     l_prev = l_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s_masked, axis=-1))
+    m_new = jnp.maximum(m_prev, jnp.max(s_masked, axis=-1, keepdims=True))
     # explicit p-masking (not just the NEG_INF bias) so fully-masked rows
     # keep l == 0 and the LSE residual stays well-defined
-    p = jnp.where(ok, jnp.exp(s - m_new[:, None]), 0.0)
+    p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
     corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_prev * corr + jnp.sum(p, axis=-1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot(
-        p.astype(v.dtype), v).astype(jnp.float32)
+    l_ref[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot(
+        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(ik == nk - 1)
     def _finalize():
         l = l_ref[...]
-        o_ref[0, :, 0, :] = (
-            acc_ref[...] / jnp.maximum(l, 1e-30)[:, None]
-        ).astype(o_ref.dtype)
-        lse_ref[0, 0, :] = jnp.where(l > 0, m_ref[...] + jnp.log(
-            jnp.maximum(l, 1e-30)), 0.0)
+        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(
+            o_ref.dtype)
+        lse = jnp.where(l > 0, m_ref[...] + jnp.log(jnp.maximum(l, 1e-30)),
+                        0.0)
+        lse_ref[0, 0] = lse.T                 # [1, bq]
 
 
 def _pad_axis(x, axis: int, pad: int, value=0):
@@ -135,6 +143,18 @@ def _pad_inputs(q, k, v, q_pos, k_pos, k_valid, block_q, block_k):
     return q, k, v, q_pos, k_pos, k_valid
 
 
+def _to_kernel_layout(q, k, v, q_pos, k_pos, k_valid):
+    """[B,S,H,hd] -> head-major [B,H,S,hd]; positions and key validity ->
+    int32 rows [B,1,S]. Every block then ends in (seq-block, hd) or
+    (1, seq-block), the shapes the TPU tiling accepts."""
+    def heads(x):
+        return x.transpose(0, 2, 1, 3)
+
+    def row(x):
+        return x.astype(jnp.int32)[:, None, :]
+    return heads(q), heads(k), heads(v), row(q_pos), row(k_pos), row(k_valid)
+
+
 def flash_attention_fwd(q, k, v, q_pos, k_pos, *, causal=True, window=0,
                         k_valid=None, block_q: int = 512,
                         block_k: int = 512, return_lse: bool = False,
@@ -152,44 +172,44 @@ def flash_attention_fwd(q, k, v, q_pos, k_pos, *, causal=True, window=0,
         q, k, v, q_pos, k_pos, k_valid, block_q, block_k)
     sq_p, sk_p = q.shape[1], k.shape[1]
     nq, nk = sq_p // block_q, sk_p // block_k
+    qt, kt, vt, qp, kp, kv = _to_kernel_layout(q, k, v, q_pos, k_pos,
+                                               k_valid)
 
     grid = (b, h, nq, nk)
     kernel = functools.partial(_fwd_kernel, causal=causal,
                                window=int(window), nk=nk, scale=hd ** -0.5)
+    q_spec = pl.BlockSpec((1, 1, block_q, hd),
+                          lambda bi, hi, iq, ik: (bi, hi, iq, 0))
+    kv_spec = pl.BlockSpec((1, 1, block_k, hd),
+                           lambda bi, hi, iq, ik: (bi, hi // g, ik, 0))
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q), lambda bi, hi, iq, ik: (bi, iq)),
-            pl.BlockSpec((1, block_k), lambda bi, hi, iq, ik: (bi, ik)),
-            pl.BlockSpec((1, block_k), lambda bi, hi, iq, ik: (bi, ik)),
-            pl.BlockSpec((1, block_q, 1, hd),
-                         lambda bi, hi, iq, ik: (bi, iq, hi, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda bi, hi, iq, ik: (bi, ik, hi // g, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda bi, hi, iq, ik: (bi, ik, hi // g, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda bi, hi, iq, ik: (bi, 0, iq)),
+            pl.BlockSpec((1, 1, block_k), lambda bi, hi, iq, ik: (bi, 0, ik)),
+            pl.BlockSpec((1, 1, block_k), lambda bi, hi, iq, ik: (bi, 0, ik)),
+            q_spec, kv_spec, kv_spec,
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, 1, hd),
-                         lambda bi, hi, iq, ik: (bi, iq, hi, 0)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda bi, hi, iq, ik: (bi, hi, iq)),
+            q_spec,
+            pl.BlockSpec((1, 1, 1, block_q),
+                         lambda bi, hi, iq, ik: (bi, hi, 0, iq)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, sq_p, h, hd), q.dtype),
-            jax.ShapeDtypeStruct((b, h, sq_p), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, sq_p, hd), q.dtype),
+            jax.ShapeDtypeStruct((b, h, 1, sq_p), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, hd), jnp.float32),       # acc
-            pltpu.VMEM((block_q,), jnp.float32),          # m
-            pltpu.VMEM((block_q,), jnp.float32),          # l
+            pltpu.VMEM((block_q, 1), jnp.float32),        # m
+            pltpu.VMEM((block_q, 1), jnp.float32),        # l
         ],
         interpret=interpret,
-    )(q_pos, k_pos, k_valid, q, k, v)
-    out = out[:, :sq]
+    )(qp, kp, kv, qt, kt, vt)
+    out = out.transpose(0, 2, 1, 3)[:, :sq]
     if return_lse:
-        return out, lse[:, :, :sq]
+        return out, lse[:, :, 0, :sq]
     return out
 
 
@@ -208,26 +228,26 @@ def _bwd_dq_kernel(qpos_ref, kpos_ref, kvalid_ref, q_ref, k_ref, v_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)         # [bq, hd]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)         # [bk, hd]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)         # [bk, hd]
-    do = do_ref[0, :, 0, :].astype(jnp.float32)       # [bq, hd]
-    lse = lse_ref[0, 0, :]                            # [bq]
-    delta = delta_ref[0, 0, :]                        # [bq]
-    qp = qpos_ref[0, :]
-    kp = kpos_ref[0, :]
-    kv = kvalid_ref[0, :]
+    q = q_ref[0, 0].astype(jnp.float32)               # [bq, hd]
+    k = k_ref[0, 0].astype(jnp.float32)               # [bk, hd]
+    v = v_ref[0, 0].astype(jnp.float32)               # [bk, hd]
+    do = do_ref[0, 0].astype(jnp.float32)             # [bq, hd]
+    lse = _col(lse_ref)                               # [bq, 1]
+    delta = _col(delta_ref)                           # [bq, 1]
+    qp = _col(qpos_ref)
+    kp = kpos_ref[0]
+    kv = kvalid_ref[0]
 
     s = jax.lax.dot_general(q * scale, k, (((1,), (1,)), ((), ())))
     ok = _block_mask(qp, kp, kv, causal, window)
-    p = jnp.where(ok, jnp.exp(s - lse[:, None]), 0.0)           # [bq, bk]
+    p = jnp.where(ok, jnp.exp(s - lse), 0.0)                    # [bq, bk]
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))   # [bq, bk]
-    ds = p * (dp - delta[:, None])
+    ds = p * (dp - delta)
     acc_ref[...] += jax.lax.dot(ds, k) * scale
 
     @pl.when(ik == nk - 1)
     def _finalize():
-        dq_ref[0, :, 0, :] = acc_ref[...].astype(dq_ref.dtype)
+        dq_ref[0, 0] = acc_ref[...].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(qpos_ref, kpos_ref, kvalid_ref, q_ref, k_ref, v_ref,
@@ -243,31 +263,31 @@ def _bwd_dkv_kernel(qpos_ref, kpos_ref, kvalid_ref, q_ref, k_ref, v_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    k = k_ref[0, :, 0, :].astype(jnp.float32)          # [bk, hd]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)          # [bk, hd]
-    qp = qpos_ref[0, :]
-    kp = kpos_ref[0, :]
-    kv = kvalid_ref[0, :]
+    k = k_ref[0, 0].astype(jnp.float32)                # [bk, hd]
+    v = v_ref[0, 0].astype(jnp.float32)                # [bk, hd]
+    qp = _col(qpos_ref)
+    kp = kpos_ref[0]
+    kv = kvalid_ref[0]
     ok = _block_mask(qp, kp, kv, causal, window)       # [bq, bk]
 
     # the G query heads of this kv head, unrolled (G is a small static int)
     for gi in range(g):
-        q = q_ref[0, :, gi, :].astype(jnp.float32)     # [bq, hd]
-        do = do_ref[0, :, gi, :].astype(jnp.float32)   # [bq, hd]
-        lse = lse_ref[0, gi, :]                        # [bq]
-        delta = delta_ref[0, gi, :]                    # [bq]
+        q = q_ref[0, gi].astype(jnp.float32)           # [bq, hd]
+        do = do_ref[0, gi].astype(jnp.float32)         # [bq, hd]
+        lse = _col(lse_ref.at[0, gi])                  # [bq, 1]
+        delta = _col(delta_ref.at[0, gi])              # [bq, 1]
         s = jax.lax.dot_general(q * scale, k, (((1,), (1,)), ((), ())))
-        p = jnp.where(ok, jnp.exp(s - lse[:, None]), 0.0)
+        p = jnp.where(ok, jnp.exp(s - lse), 0.0)
         dv_acc[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())))
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-        ds = p * (dp - delta[:, None])
+        ds = p * (dp - delta)
         dk_acc[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ()))) * scale
 
     @pl.when(iq == nq - 1)
     def _finalize():
-        dk_ref[0, :, 0, :] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0, :, 0, :] = dv_acc[...].astype(dv_ref.dtype)
+        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def flash_attention_bwd(q, k, v, q_pos, k_pos, k_valid, out, lse, do, *,
@@ -288,79 +308,69 @@ def flash_attention_bwd(q, k, v, q_pos, k_pos, k_valid, out, lse, do, *,
     delta = jnp.einsum("bqhd,bqhd->bhq", do.astype(jnp.float32),
                        out.astype(jnp.float32))
 
-    qp, kp = q_pos, k_pos
-    q_p, k_p, v_p, qp, kp, kv = _pad_inputs(q, k, v, qp, kp, k_valid,
+    q_p, k_p, v_p, qp, kp, kv = _pad_inputs(q, k, v, q_pos, k_pos, k_valid,
                                             block_q, block_k)
-    do_p = _pad_axis(do, 1, q_p.shape[1] - sq)
-    lse_p = _pad_axis(lse, 2, q_p.shape[1] - sq)
-    delta_p = _pad_axis(delta, 2, q_p.shape[1] - sq)
+    pad_q = q_p.shape[1] - sq
+    do_p = _pad_axis(do, 1, pad_q)
+    lse_p = _pad_axis(lse, 2, pad_q)[:, :, None, :]          # [B,H,1,Sq]
+    delta_p = _pad_axis(delta, 2, pad_q)[:, :, None, :]
     sq_p, sk_p = q_p.shape[1], k_p.shape[1]
     nq, nk = sq_p // block_q, sk_p // block_k
+    qt, kt, vt, qp, kp, kv = _to_kernel_layout(q_p, k_p, v_p, qp, kp, kv)
+    dot = do_p.transpose(0, 2, 1, 3)
 
+    q_spec = pl.BlockSpec((1, 1, block_q, hd),
+                          lambda bi, hi, iq, ik: (bi, hi, iq, 0))
+    kv_spec = pl.BlockSpec((1, 1, block_k, hd),
+                           lambda bi, hi, iq, ik: (bi, hi // g, ik, 0))
+    row_q = pl.BlockSpec((1, 1, 1, block_q),
+                         lambda bi, hi, iq, ik: (bi, hi, 0, iq))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, causal=causal, window=int(window),
                           nk=nk, scale=scale),
         grid=(b, h, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q), lambda bi, hi, iq, ik: (bi, iq)),
-            pl.BlockSpec((1, block_k), lambda bi, hi, iq, ik: (bi, ik)),
-            pl.BlockSpec((1, block_k), lambda bi, hi, iq, ik: (bi, ik)),
-            pl.BlockSpec((1, block_q, 1, hd),
-                         lambda bi, hi, iq, ik: (bi, iq, hi, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda bi, hi, iq, ik: (bi, ik, hi // g, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda bi, hi, iq, ik: (bi, ik, hi // g, 0)),
-            pl.BlockSpec((1, block_q, 1, hd),
-                         lambda bi, hi, iq, ik: (bi, iq, hi, 0)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda bi, hi, iq, ik: (bi, hi, iq)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda bi, hi, iq, ik: (bi, hi, iq)),
+            pl.BlockSpec((1, 1, block_q), lambda bi, hi, iq, ik: (bi, 0, iq)),
+            pl.BlockSpec((1, 1, block_k), lambda bi, hi, iq, ik: (bi, 0, ik)),
+            pl.BlockSpec((1, 1, block_k), lambda bi, hi, iq, ik: (bi, 0, ik)),
+            q_spec, kv_spec, kv_spec, q_spec, row_q, row_q,
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, hd),
-                               lambda bi, hi, iq, ik: (bi, iq, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sq_p, h, hd), q.dtype),
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, sq_p, hd), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
         interpret=interpret,
-    )(qp, kp, kv, q_p, k_p, v_p, do_p, lse_p, delta_p)
+    )(qp, kp, kv, qt, kt, vt, dot, lse_p, delta_p)
 
+    # grid (b, kv-head, kv-block, q-block): the G query heads of kv head
+    # ki are the contiguous head block ki of the head-major layout
+    qg_spec = pl.BlockSpec((1, g, block_q, hd),
+                           lambda bi, ki, ik, iq: (bi, ki, iq, 0))
+    k_spec = pl.BlockSpec((1, 1, block_k, hd),
+                          lambda bi, ki, ik, iq: (bi, ki, ik, 0))
+    rowg_q = pl.BlockSpec((1, g, 1, block_q),
+                          lambda bi, ki, ik, iq: (bi, ki, 0, iq))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, causal=causal, window=int(window),
                           nq=nq, g=g, scale=scale),
         grid=(b, kh, nk, nq),
         in_specs=[
-            pl.BlockSpec((1, block_q), lambda bi, ki, ik, iq: (bi, iq)),
-            pl.BlockSpec((1, block_k), lambda bi, ki, ik, iq: (bi, ik)),
-            pl.BlockSpec((1, block_k), lambda bi, ki, ik, iq: (bi, ik)),
-            pl.BlockSpec((1, block_q, g, hd),
-                         lambda bi, ki, ik, iq: (bi, iq, ki, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda bi, ki, ik, iq: (bi, ik, ki, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda bi, ki, ik, iq: (bi, ik, ki, 0)),
-            pl.BlockSpec((1, block_q, g, hd),
-                         lambda bi, ki, ik, iq: (bi, iq, ki, 0)),
-            pl.BlockSpec((1, g, block_q),
-                         lambda bi, ki, ik, iq: (bi, ki, iq)),
-            pl.BlockSpec((1, g, block_q),
-                         lambda bi, ki, ik, iq: (bi, ki, iq)),
+            pl.BlockSpec((1, 1, block_q), lambda bi, ki, ik, iq: (bi, 0, iq)),
+            pl.BlockSpec((1, 1, block_k), lambda bi, ki, ik, iq: (bi, 0, ik)),
+            pl.BlockSpec((1, 1, block_k), lambda bi, ki, ik, iq: (bi, 0, ik)),
+            qg_spec, k_spec, k_spec, qg_spec, rowg_q, rowg_q,
         ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda bi, ki, ik, iq: (bi, ik, ki, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda bi, ki, ik, iq: (bi, ik, ki, 0)),
-        ],
+        out_specs=[k_spec, k_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((b, sk_p, kh, hd), k.dtype),
-            jax.ShapeDtypeStruct((b, sk_p, kh, hd), v.dtype),
+            jax.ShapeDtypeStruct((b, kh, sk_p, hd), k.dtype),
+            jax.ShapeDtypeStruct((b, kh, sk_p, hd), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, hd), jnp.float32),
             pltpu.VMEM((block_k, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(qp, kp, kv, q_p, k_p, v_p, do_p, lse_p, delta_p)
+    )(qp, kp, kv, qt, kt, vt, dot, lse_p, delta_p)
 
-    return dq[:, :sq], dk[:, :sk], dv[:, :sk]
+    def seq_major(x, n):
+        return x.transpose(0, 2, 1, 3)[:, :n]
+    return seq_major(dq, sq), seq_major(dk, sk), seq_major(dv, sk)

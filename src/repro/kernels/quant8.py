@@ -54,8 +54,10 @@ def _kernel_sr_tpu(seed_ref, x_ref, y_ref, *, qmax: float):
     x = x_ref[...].astype(jnp.float32)
     scale = jnp.maximum(jnp.max(jnp.abs(x), axis=-1, keepdims=True) / qmax,
                         1e-12)
-    bits = pltpu.bitcast(pltpu.prng_random_bits(x.shape), jnp.uint32)
-    u = (bits >> 8).astype(jnp.float32) * (2.0 ** -24)   # U[0, 1)
+    # top 24 random bits as a non-negative int32 (the TPU has no
+    # uint32 -> float32 convert), scaled to U[0, 1)
+    bits = pltpu.bitcast(pltpu.prng_random_bits(x.shape), jnp.int32)
+    u = jax.lax.shift_right_logical(bits, 8).astype(jnp.float32) * (2.0 ** -24)
     q = jnp.floor(x / scale + u)
     y_ref[...] = (jnp.clip(q, -qmax, qmax) * scale).astype(y_ref.dtype)
 

@@ -19,11 +19,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     return jax.make_mesh(shape, axes)
 
 
-def make_host_mesh():
-    """Whatever devices exist locally, as a (data, model) mesh with
-    model = 1 — used by tests/benchmarks on CPU."""
-    n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+def make_host_mesh(devices=None):
+    """``devices`` (default: every device the process sees) as a
+    (data, model) mesh with model = 1: the MPSL client axis and FSDP both
+    run over ``data``."""
+    devices = list(devices) if devices is not None else jax.devices()
+    auto = jax.sharding.AxisType.Auto
+    return jax.make_mesh((len(devices), 1), ("data", "model"),
+                         axis_types=(auto, auto), devices=devices)
 
 
 # TPU v5e-class hardware constants for the roofline (per chip / per link)

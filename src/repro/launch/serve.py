@@ -17,6 +17,7 @@ import numpy as np
 
 from repro import obs
 from repro.configs import get_config, reduced
+from repro.launch.compile_cache import enable_compilation_cache
 from repro.models import layers, model as M
 
 
@@ -55,6 +56,7 @@ def main(argv=None):
     p.add_argument("--obs-log", default=None,
                    help="write a JSONL telemetry run log to this path")
     args = p.parse_args(argv)
+    enable_compilation_cache()
 
     log = obs.get_logger("serve")
     if args.obs_log:

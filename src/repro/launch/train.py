@@ -2,18 +2,22 @@
 
   PYTHONPATH=src python -m repro.launch.train --arch minitron-4b \
       --steps 50 --reduced --ckpt-dir /tmp/ckpt
+  python -m repro.launch.train --arch hymba-1.5b --full \
+      --trainable-blocks 2 --batch-per-client 1 --seq 4096
 
-Full-size configs target the production mesh (use dryrun.py for those);
---reduced trains the same-family small config end-to-end on host devices
-(this is what CI / the examples use).
+The run uses every device the process sees: one (data, model) host mesh
+with the MPSL client axis and FSDP over ``data``. The state is
+initialised under jit straight into its shardings, so no device ever
+holds the whole f32 parameter tree. --reduced trains the same-family
+small config in float32 (what CI and the examples use); --full runs the
+published widths in the RunConfig compute dtype (bfloat16).
 """
 from __future__ import annotations
 
 import argparse
-import dataclasses
+import functools
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro import faults, obs
@@ -22,6 +26,7 @@ from repro.core import mpsl, split
 from repro.data import (ClientLoader, PrefetchLoader, SyntheticLM,
                         dirichlet_partition)
 from repro.launch import mesh as mesh_lib
+from repro.launch.compile_cache import enable_compilation_cache
 from repro.optim import schedules
 from repro.parallel import sharding
 from repro.train import Trainer, TrainerConfig
@@ -46,7 +51,7 @@ def make_lm_loader(cfg, n_clients: int, bn: int, seq: int, seed: int = 0,
     return LMWrapper()
 
 
-def main(argv=None):
+def parse_args(argv=None):
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="minitron-4b")
     p.add_argument("--steps", type=int, default=50)
@@ -61,6 +66,8 @@ def main(argv=None):
     p.add_argument("--compress", action="store_true")
     p.add_argument("--ckpt-dir", default=None)
     p.add_argument("--ckpt-every", type=int, default=25)
+    p.add_argument("--log-every", type=int, default=10,
+                   help="read the loss back every N steps (1 = each step)")
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--prefetch", type=int, default=2,
                    help="prefetch depth (0 = synchronous loader)")
@@ -80,9 +87,53 @@ def main(argv=None):
                         "(non-finite step guard, producer/checkpoint "
                         "retries)")
     p.add_argument("--profile-dir", default=None,
-                   help="opt-in jax.profiler trace window directory")
-    args = p.parse_args(argv)
+                   help="jax.profiler trace of steps 5-6 into this "
+                        "directory; the run fails if the trace cannot "
+                        "start or stop")
+    return p.parse_args(argv)
 
+
+def make_run_config(args, **overrides):
+    """(model config, RunConfig) for parsed args; ``overrides`` replace
+    RunConfig fields (e.g. ``attn_impl``)."""
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = reduced(cfg)
+        overrides.setdefault("compute_dtype", "float32")
+    mp = MPSLConfig(n_clients=args.n_clients,
+                    trainable_blocks=args.trainable_blocks,
+                    compress_uplink=args.compress,
+                    compress_downlink=args.compress)
+    run = RunConfig(model=cfg, shape=SHAPES["train_4k"], mpsl=mp,
+                    learning_rate=args.lr, seed=args.seed, **overrides)
+    return cfg, run
+
+
+def init_train_state(key, cfg, run, mesh):
+    """The MPSL train state, initialised under jit directly into its
+    shardings on ``mesh``: parameters are created shard by shard, and the
+    frozen part is cast to its storage dtype inside the same program."""
+    def init(key):
+        params, frozen, _ = split.init_mpsl_lm(key, cfg, run)
+        return mpsl.init_state(params, frozen, run.seed)
+
+    shardings = mpsl.state_shardings(jax.eval_shape(init, key), mesh)
+    return jax.jit(init, out_shardings=shardings)(key)
+
+
+def make_step_fn(cfg, run, args, guard_nonfinite: bool = False):
+    loss_fn = mpsl.make_lm_loss(cfg, run)
+    sched = schedules.warmup_cosine(args.lr, 10, args.steps)
+    return mpsl.jit_train_step(
+        mpsl.make_train_step(loss_fn, run, sched,
+                             guard_nonfinite=guard_nonfinite),
+        donate=args.donate)
+
+
+def train(args):
+    """Run the MPSL trainer for parsed ``args`` on every device the
+    process sees and return the Trainer's result."""
+    enable_compilation_cache()
     log = obs.get_logger("train")
     if args.obs_log:
         obs.configure(args.obs_log,
@@ -105,38 +156,30 @@ def main(argv=None):
                  n_events=len(fault_plan.events),
                  kinds=fault_plan.kinds_present())
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = reduced(cfg)
-    mp = MPSLConfig(n_clients=args.n_clients,
-                    trainable_blocks=args.trainable_blocks,
-                    compress_uplink=args.compress,
-                    compress_downlink=args.compress)
-    run = RunConfig(model=cfg, shape=SHAPES["train_4k"], mpsl=mp,
-                    compute_dtype="float32", learning_rate=args.lr,
-                    seed=args.seed)
-
-    key = jax.random.PRNGKey(args.seed)
-    params, frozen, plan = split.init_mpsl_lm(key, cfg, run)
-    state = mpsl.place_state(mpsl.init_state(params, frozen, args.seed))
-    loss_fn = mpsl.make_lm_loss(cfg, run)
-    sched = schedules.warmup_cosine(args.lr, 10, args.steps)
-    step_fn = mpsl.jit_train_step(
-        mpsl.make_train_step(loss_fn, run, sched,
-                             guard_nonfinite=fault_plan is not None),
-        donate=args.donate)
-
-    loader = PrefetchLoader(
-        make_lm_loader(cfg, args.n_clients, args.batch_per_client,
-                       args.seq, args.seed, args.drop_prob),
-        depth=args.prefetch, place_fn=sharding.place_batch)
-    trainer = Trainer(step_fn, state, loader,
-                      TrainerConfig(total_steps=args.steps,
-                                    ckpt_every=args.ckpt_every,
-                                    ckpt_dir=args.ckpt_dir,
-                                    profile_dir=args.profile_dir))
-    result = trainer.run()
-    loader.close()
+    cfg, run = make_run_config(args)
+    mesh = mesh_lib.make_host_mesh()
+    with sharding.use_mesh(mesh):
+        state = init_train_state(jax.random.PRNGKey(args.seed), cfg, run,
+                                 mesh)
+        step_fn = make_step_fn(cfg, run, args,
+                               guard_nonfinite=fault_plan is not None)
+        # the producer thread does not see this thread's mesh context
+        loader = PrefetchLoader(
+            make_lm_loader(cfg, args.n_clients, args.batch_per_client,
+                           args.seq, args.seed, args.drop_prob),
+            depth=args.prefetch,
+            place_fn=functools.partial(sharding.place_batch, mesh=mesh))
+        trainer = Trainer(step_fn, state, loader,
+                          TrainerConfig(total_steps=args.steps,
+                                        ckpt_every=args.ckpt_every,
+                                        ckpt_dir=args.ckpt_dir,
+                                        log_every=args.log_every,
+                                        profile_dir=args.profile_dir))
+        del state
+        try:
+            result = trainer.run()
+        finally:
+            loader.close()
     log.info(f"done: final loss {result['final_loss']:.4f} "
              f"({result['steps_per_sec']:.2f} steps/s, "
              f"host stall {100 * result['host_stall_frac']:.0f}%)",
@@ -155,6 +198,11 @@ def main(argv=None):
         obs.shutdown()
         log.info(f"run log -> {args.obs_log} "
                  f"(python -m repro.obs.report {args.obs_log})")
+    return result
+
+
+def main(argv=None):
+    train(parse_args(argv))
     return 0
 
 

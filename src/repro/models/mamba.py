@@ -12,6 +12,7 @@ what makes the 524k-context cells feasible for the SSM/hybrid archs.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -82,6 +83,12 @@ def chunked_selective_scan(x, dt, b_in, c_in, a_log, h0=None, chunk=256):
     h_init = (jnp.zeros((bsz, di, ds), jnp.float32)
               if h0 is None else h0.astype(jnp.float32))
 
+    # Each chunk is checkpointed: its discretized operands and associative-
+    # scan levels ([B, chunk, di, ds] f32 apiece) are recomputed in the
+    # backward pass from the carried state, instead of being stashed for
+    # every chunk ([nc, B, chunk, di, ds] — tens of GB at hymba width).
+    @functools.partial(jax.checkpoint,
+                       policy=jax.checkpoint_policies.nothing_saveable)
     def step(h, blk):
         xc, dtc, bc, cc = (t.astype(jnp.float32) for t in blk)
         a = jnp.exp(dtc[..., None] * a_neg)                # [B, c, di, ds]

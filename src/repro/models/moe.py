@@ -122,7 +122,6 @@ def _apply_ep(params, x, cfg, weights, idx, capacity_factor: float = 2.0):
     Semantically exact up to capacity overflow (2x slack; the router aux
     loss keeps loads balanced)."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.parallel import sharding as sh
 
     mesh = sh.current_mesh()
@@ -187,12 +186,12 @@ def _apply_ep(params, x, cfg, weights, idx, capacity_factor: float = 2.0):
     tok_spec = P(batch_spec[0], None)
     wi_spec = P("model", "data" if "data" in mesh.axis_names else None, None)
     wo_spec = P("model", None, "data" if "data" in mesh.axis_names else None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(tok_spec, P(batch_spec[0], None), P(batch_spec[0], None),
                   wi_spec, wi_spec, wo_spec),
         out_specs=tok_spec,
-        check_rep=False)
+        check_vma=False)
     wg = params.get("wg", params["wi"])
     return fn(x, weights, idx, params["wi"], wg, params["wo"])
 

@@ -10,7 +10,8 @@ readback cadence and the run-level synchronized steps/sec).
 
 For deep dives where device-side timing IS wanted, ``ProfileWindow``
 arms an opt-in ``jax.profiler`` trace over a bounded step window; it is
-entirely inert unless a log directory is given.
+entirely inert unless a log directory is given, and fails the run when
+the trace it was given cannot be taken.
 """
 from __future__ import annotations
 
@@ -23,14 +24,15 @@ class ProfileWindow:
     """Opt-in ``jax.profiler`` trace over steps [start, start+num).
 
     The trainer calls ``on_step(step)`` at the top of every iteration
-    and ``stop()`` on exit; with ``logdir=None`` both are no-ops. Any
-    profiler failure (unsupported backend, missing deps) disables the
-    window rather than killing the run — profiling must never be
-    load-bearing.
+    and ``stop()`` on exit; with ``logdir=None`` both are no-ops. Once a
+    directory is given the trace is what the run was asked for: a
+    profiler that cannot start or stop raises (after recording a
+    ``profile/*_failed`` error event) instead of letting the run exit 0
+    without it.
     """
 
     def __init__(self, logdir: Optional[str], start_step: int = 5,
-                 num_steps: int = 3):
+                 num_steps: int = 2):
         self.logdir = logdir
         self.start = int(start_step)
         self.num = max(1, int(num_steps))
@@ -41,14 +43,15 @@ class ProfileWindow:
         if self._done:
             return
         if not self._active and step >= self.start:
+            import jax
             try:
-                import jax
                 jax.profiler.start_trace(self.logdir)
-            except Exception as e:  # profiling is best-effort
+            except Exception as e:
                 self._done = True
                 _rec.event("profile/start_failed", level="error",
                            error=repr(e))
-                return
+                raise RuntimeError(
+                    f"profiler trace into {self.logdir} did not start") from e
             self._active = True
             _rec.event("profile/started", logdir=self.logdir, step=step)
         elif self._active and step >= self.start + self.num:
@@ -58,11 +61,13 @@ class ProfileWindow:
         if not self._active:
             self._done = True
             return
-        try:
-            import jax
-            jax.profiler.stop_trace()
-            _rec.event("profile/stopped", logdir=self.logdir)
-        except Exception as e:
-            _rec.event("profile/stop_failed", level="error", error=repr(e))
+        import jax
         self._active = False
         self._done = True
+        try:
+            jax.profiler.stop_trace()
+        except Exception as e:
+            _rec.event("profile/stop_failed", level="error", error=repr(e))
+            raise RuntimeError(
+                f"profiler trace into {self.logdir} did not stop") from e
+        _rec.event("profile/stopped", logdir=self.logdir)

@@ -89,7 +89,7 @@ class TrainerConfig:
     # the span telemetry never measures device time, by design)
     profile_dir: Optional[str] = None
     profile_start: int = 5
-    profile_steps: int = 3
+    profile_steps: int = 2
 
 
 class Trainer:

@@ -1,6 +1,7 @@
 """Pallas kernel validation: shape/dtype sweeps against the pure-jnp
 oracles in repro.kernels.ref, executed under interpret=True on CPU."""
-from _compat import hypothesis, st
+import hypothesis
+from hypothesis import strategies as st
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -3,7 +3,8 @@ properties, MoE dense vs ragged dispatch, Mamba chunk invariance,
 tokenizers."""
 import dataclasses
 
-from _compat import hypothesis, st
+import hypothesis
+from hypothesis import strategies as st
 import jax
 import jax.numpy as jnp
 import numpy as np

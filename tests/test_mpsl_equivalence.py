@@ -11,7 +11,8 @@
 """
 import dataclasses
 
-from _compat import hypothesis, st
+import hypothesis
+from hypothesis import strategies as st
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -16,7 +16,8 @@ Shapes stay tiny on purpose: these check algebra via the jnp reference
 (plus one kernel-path segmentation case), not kernel tilings — those live
 in test_kernel_grads.py / test_kernels.py.
 """
-from _compat import hypothesis, st
+import hypothesis
+from hypothesis import strategies as st
 import jax
 import jax.numpy as jnp
 import numpy as np

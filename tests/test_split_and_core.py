@@ -1,7 +1,8 @@
 """Split/assemble, aggregation, fusion, losses, compression."""
 import dataclasses
 
-from _compat import hypothesis, st
+import hypothesis
+from hypothesis import strategies as st
 import jax
 import jax.numpy as jnp
 import numpy as np

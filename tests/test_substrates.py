@@ -2,7 +2,8 @@
 checkpoint round-trips, optimizer, schedules."""
 import os
 
-from _compat import hypothesis, st
+import hypothesis
+from hypothesis import strategies as st
 import jax
 import jax.numpy as jnp
 import numpy as np
