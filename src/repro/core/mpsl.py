@@ -75,14 +75,18 @@ def _run_body(frozen, server, cfg, h, positions, impls, remat,
     """Frozen prefix + trainable suffix, then final norm."""
     aux = jnp.zeros((), jnp.float32)
     fsegs, tsegs = _segments_for(frozen, server, cfg)
-    for sp, seg in zip(frozen["segments"], fsegs):
-        h, _, a = M.apply_segment(sp, h, cfg, seg, positions=positions,
-                                  enc_out=enc_out, impls=impls, remat=remat)
-        aux = aux + a
-    for sp, seg in zip(server["segments"], tsegs):
-        h, _, a = M.apply_segment(sp, h, cfg, seg, positions=positions,
-                                  enc_out=enc_out, impls=impls, remat=remat)
-        aux = aux + a
+    with jax.named_scope("frozen_trunk"):
+        for sp, seg in zip(frozen["segments"], fsegs):
+            h, _, a = M.apply_segment(sp, h, cfg, seg, positions=positions,
+                                      enc_out=enc_out, impls=impls,
+                                      remat=remat)
+            aux = aux + a
+    with jax.named_scope("trainable_trunk"):
+        for sp, seg in zip(server["segments"], tsegs):
+            h, _, a = M.apply_segment(sp, h, cfg, seg, positions=positions,
+                                      enc_out=enc_out, impls=impls,
+                                      remat=remat)
+            aux = aux + a
     h = layers.apply_norm(h, server["final_norm"], cfg.norm)
     return h, aux
 
@@ -120,16 +124,17 @@ def make_lm_loss(cfg, run):
         r_up, r_down = jax.random.split(jax.random.fold_in(rng, 1))
 
         # ---- 1. client forward: frozen tokenizer + per-client adapter ----
-        h = frozen["embed"]["table"].astype(cdt)[tokens]       # [N,Bn,S,D]
-        if cfg.pos_embed == "learned":
-            h = h + frozen["embed"]["pos"].astype(cdt)[
-                layers.positions_from_shape(1, s_text)[0]]
-        parts = [h]
-        if "patch_embeds" in batch:
-            parts = [batch["patch_embeds"].astype(cdt), h]
-        h = jnp.concatenate(parts, axis=2) if len(parts) > 1 else h
-        h = split.apply_client_adapter(trainable["client"]["adapter"], h)
-        h = sharding.shard_act(h, ("client", None, None, None))
+        with jax.named_scope("client_head"):
+            h = frozen["embed"]["table"].astype(cdt)[tokens]   # [N,Bn,S,D]
+            if cfg.pos_embed == "learned":
+                h = h + frozen["embed"]["pos"].astype(cdt)[
+                    layers.positions_from_shape(1, s_text)[0]]
+            parts = [h]
+            if "patch_embeds" in batch:
+                parts = [batch["patch_embeds"].astype(cdt), h]
+            h = jnp.concatenate(parts, axis=2) if len(parts) > 1 else h
+            h = split.apply_client_adapter(trainable["client"]["adapter"], h)
+            h = sharding.shard_act(h, ("client", None, None, None))
 
         # ---- 2. uplink (smashed data) ----
         _account_links(h, mpsl)
@@ -229,15 +234,16 @@ def make_vit_loss(cfg, run, modalities=("vision", "text"),
 
         # ---- client tokenizers (per-client params, vmapped) ----
         tokenized = {}
-        for m in modalities:
-            spec = tok.MODALITIES[m]
-            x = batch[m]
-            f = functools.partial(tok.apply_tokenizer, spec=spec, dtype=cdt)
-            tokenized[m] = jax.vmap(
-                lambda p, xx: f(p, xx))(trainable["client"]["tokenizers"][m],
-                                        x)
-            tokenized[m] = sharding.shard_act(
-                tokenized[m], ("client", None, None, None))
+        with jax.named_scope("client_head"):
+            for m in modalities:
+                spec = tok.MODALITIES[m]
+                x = batch[m]
+                f = functools.partial(tok.apply_tokenizer, spec=spec,
+                                      dtype=cdt)
+                tokenized[m] = jax.vmap(lambda p, xx: f(p, xx))(
+                    trainable["client"]["tokenizers"][m], x)
+                tokenized[m] = sharding.shard_act(
+                    tokenized[m], ("client", None, None, None))
 
         bn = next(iter(tokenized.values())).shape[1]
 
@@ -365,12 +371,13 @@ def make_train_step(loss_fn, run, sched, backward_mode: str = "aggregated",
         else:
             grads, loss, metrics = _per_client_grads(
                 loss_fn, state["params"], state["frozen"], batch, rng)
-        grads, gnorm = clip_by_global_norm(grads, run.grad_clip)
-        lr = sched(state["step"])
-        updates, opt = adamw_update(
-            grads, state["opt"], state["params"], lr=lr,
-            weight_decay=run.weight_decay)
-        params = apply_updates(state["params"], updates)
+        with jax.named_scope("optimizer"):
+            grads, gnorm = clip_by_global_norm(grads, run.grad_clip)
+            lr = sched(state["step"])
+            updates, opt = adamw_update(
+                grads, state["opt"], state["params"], lr=lr,
+                weight_decay=run.weight_decay)
+            params = apply_updates(state["params"], updates)
         metrics = dict(metrics, grad_norm=gnorm, lr=lr)
         if guard_nonfinite:
             ok = jnp.isfinite(loss) & jnp.isfinite(gnorm)

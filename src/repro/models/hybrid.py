@@ -33,12 +33,14 @@ def apply_hybrid(params, x, cfg, *, positions, is_global, cache=None,
     kv_cache = cache["kv"] if cache is not None else None
     ssm_cache = cache["ssm"] if cache is not None else None
 
-    a_out, kv_new = attention.apply_attention(
-        params["attn"], x, cfg, positions=positions, causal=True,
-        window=window, cache=kv_cache, impl=impl, seq_shard=seq_shard)
-    s_out, ssm_new = mamba.apply_mamba(
-        params["ssm"], x, cfg, cache=ssm_cache, impl=ssm_impl,
-        bwd_impl=ssm_bwd)
+    with jax.named_scope("attention"):
+        a_out, kv_new = attention.apply_attention(
+            params["attn"], x, cfg, positions=positions, causal=True,
+            window=window, cache=kv_cache, impl=impl, seq_shard=seq_shard)
+    with jax.named_scope("ssm"):
+        s_out, ssm_new = mamba.apply_mamba(
+            params["ssm"], x, cfg, cache=ssm_cache, impl=ssm_impl,
+            bwd_impl=ssm_bwd)
 
     a_out = layers.rms_norm(a_out, params["attn_norm"]["scale"])
     s_out = layers.rms_norm(s_out, params["ssm_norm"]["scale"])

@@ -110,10 +110,12 @@ def apply_block(params, x, cfg, kind: BlockKind, *, positions, cache=None,
 
     new_cache = None
     if kind.family == "ssm":
-        out, new_cache = mamba.apply_mamba(
-            params["ssm"], h, cfg, cache=cache,
-            impl=impls.get("ssm", "jnp"), chunk=impls.get("ssm_chunk", 256),
-            bwd_impl=impls.get("ssm_bwd", "fused"))
+        with jax.named_scope("ssm"):
+            out, new_cache = mamba.apply_mamba(
+                params["ssm"], h, cfg, cache=cache,
+                impl=impls.get("ssm", "jnp"),
+                chunk=impls.get("ssm_chunk", 256),
+                bwd_impl=impls.get("ssm_bwd", "fused"))
         return x + out, new_cache, aux
     if kind.family == "hybrid":
         out, new_cache = hybrid.apply_hybrid(
@@ -125,11 +127,13 @@ def apply_block(params, x, cfg, kind: BlockKind, *, positions, cache=None,
         x = x + out
     else:
         window = 0 if kind.is_global else cfg.sliding_window
-        out, new_cache = attention.apply_attention(
-            params["attn"], h, cfg, positions=positions, causal=kind.causal,
-            window=window, cache=cache, impl=impls.get("attn", "auto"),
-            block=impls.get("attn_block", 1024),
-            seq_shard=impls.get("attn_seq_shard", False))
+        with jax.named_scope("attention"):
+            out, new_cache = attention.apply_attention(
+                params["attn"], h, cfg, positions=positions,
+                causal=kind.causal, window=window, cache=cache,
+                impl=impls.get("attn", "auto"),
+                block=impls.get("attn_block", 1024),
+                seq_shard=impls.get("attn_seq_shard", False))
         x = x + out
 
     if kind.cross:

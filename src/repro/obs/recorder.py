@@ -9,13 +9,18 @@ it. Two rules enforce that:
     singletons: no allocation, no I/O, no lock. The hot loop pays one
     attribute lookup per span when telemetry is disabled.
   * host-side only — the recorder never touches device values. Spans
-    close on wall clock; device metrics keep flowing through the
+    close on the host clock; device metrics keep flowing through the
     existing ``MetricsRing`` readback cadence; link byte accounting
     (``repro.obs.comm``) happens at trace time from static shapes.
 
+An enabled recorder's span also enters a ``jax.profiler.TraceAnnotation``
+of the same name, with the span's fields as its metadata, so a profiler
+trace taken meanwhile shows each span on its host thread, on the clock
+of the device's events.
+
 Record schema (one JSON object per line):
 
-  {"ts": <unix s>, "kind": "meta|event|counter|gauge|span|hist|link",
+  {"ts": <unix s>, "kind": "meta|event|counter|gauge|span|link",
    "name": str, ...kind-specific fields...}
 
   meta    — run metadata, written once at configure time.
@@ -24,8 +29,6 @@ Record schema (one JSON object per line):
   counter — monotonically accumulated value (emitted per bump).
   gauge   — instantaneous value (queue depth, loss, ...).
   span    — {"dur_s": wall duration, "fields": {...}} closed on exit.
-  hist    — in-memory aggregation (count/sum/min/max + pow-2 buckets)
-            emitted at ``emit_hists()``/``close()`` boundaries.
   link    — a communication-link record from ``repro.obs.comm``
             (deduplicated per recorder by name+shape).
 """
@@ -33,12 +36,13 @@ from __future__ import annotations
 
 import contextlib
 import json
-import math
 import os
 import threading
 import time
 import uuid
 from typing import Any, Callable, Dict, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 def _jsonable(x):
@@ -85,13 +89,7 @@ class NullRecorder:
     def gauge(self, name, value, **fields):
         pass
 
-    def observe(self, name, value):
-        pass
-
     def link(self, record):
-        pass
-
-    def emit_hists(self):
         pass
 
     def flush(self):
@@ -106,51 +104,30 @@ class NullRecorder:
 
 
 class _Span:
-    __slots__ = ("_rec", "name", "fields", "t0")
+    __slots__ = ("_rec", "name", "fields", "t0", "_annotation")
 
     def __init__(self, rec: "Recorder", name: str, fields: Dict[str, Any]):
         self._rec = rec
         self.name = name
         self.fields = fields
         self.t0 = 0.0
+        self._annotation = None
 
     def __enter__(self):
+        self._annotation = TraceAnnotation(self.name, **self.fields)
+        self._annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         dur = time.perf_counter() - self.t0
+        self._annotation.__exit__(exc_type, exc, tb)
         if exc is not None:
             self.fields = dict(self.fields, error=repr(exc))
         self._rec._emit({"kind": "span", "name": self.name,
                          "dur_s": dur, "fields": self.fields},
                         urgent=exc is not None)
         return False
-
-
-class _Hist:
-    __slots__ = ("count", "sum", "min", "max", "buckets")
-
-    def __init__(self):
-        self.count = 0
-        self.sum = 0.0
-        self.min = math.inf
-        self.max = -math.inf
-        self.buckets: Dict[str, int] = {}
-
-    def add(self, v: float):
-        v = float(v)
-        self.count += 1
-        self.sum += v
-        self.min = min(self.min, v)
-        self.max = max(self.max, v)
-        key = "0" if v <= 0 else f"{2.0 ** math.ceil(math.log2(v)):g}"
-        self.buckets[key] = self.buckets.get(key, 0) + 1
-
-    def record(self, name: str) -> Dict[str, Any]:
-        return {"kind": "hist", "name": name, "count": self.count,
-                "sum": self.sum, "min": self.min, "max": self.max,
-                "buckets": self.buckets}
 
 
 class Recorder:
@@ -176,7 +153,6 @@ class Recorder:
         self._lock = threading.Lock()
         self._buf: list = []
         self._flush_every = int(flush_every)
-        self._hists: Dict[str, _Hist] = {}
         self._links_seen: set = set()
         self._counters: Dict[str, float] = {}
         parent = os.path.dirname(os.path.abspath(self.path))
@@ -241,13 +217,6 @@ class Recorder:
         self._emit({"kind": "gauge", "name": name, "value": value,
                     "fields": fields})
 
-    def observe(self, name: str, value):
-        with self._lock:
-            h = self._hists.get(name)
-            if h is None:
-                h = self._hists[name] = _Hist()
-            h.add(value)
-
     def link(self, record: Dict[str, Any]):
         # dedup on full content: identical re-records (retrace, scan) are
         # dropped, refinements (e.g. quantized_in_trace) pass through
@@ -259,12 +228,6 @@ class Recorder:
             self._links_seen.add(key)
         self._emit(dict(record, kind="link"), urgent=True)
 
-    def emit_hists(self):
-        with self._lock:
-            recs = [h.record(n) for n, h in self._hists.items()]
-        for r in recs:
-            self._emit(r)
-
     def flush(self):
         with self._lock:
             self._flush_locked()
@@ -272,7 +235,6 @@ class Recorder:
     def close(self):
         if self._closed:
             return
-        self.emit_hists()
         with self._lock:
             self._flush_locked()
             self._closed = True
@@ -342,10 +304,6 @@ def counter(name: str, value=1, **fields):
 
 def gauge(name: str, value, **fields):
     get().gauge(name, value, **fields)
-
-
-def observe(name: str, value):
-    get().observe(name, value)
 
 
 # ---------------------------------------------------------------------------

@@ -24,7 +24,6 @@ Sections:
                         pairs every injection with a recovery and the
                         span/link tables look like a clean run's
   * counters / gauges — final totals and last-seen gauge values
-  * histograms        — recorder-side aggregations (step wall time)
   * events            — error events in full, info events counted
   * bench             — optional BENCH_pipeline.json steps/sec
                         trajectory next to the measured spans
@@ -196,17 +195,6 @@ def render(records: List[Dict[str, Any]],
             lines.append(f"counter {n} = {v}")
         for n, v in sorted(gauges.items()):
             lines.append(f"gauge   {n} = {v}")
-
-    hists = [r for r in records if r.get("kind") == "hist"]
-    seen_hist = {}
-    for h in hists:
-        seen_hist[h["name"]] = h          # last emission wins
-    if seen_hist:
-        lines += ["", "== histograms =="]
-        for n, h in sorted(seen_hist.items()):
-            mean = h["sum"] / h["count"] if h.get("count") else 0.0
-            lines.append(f"{n}: n={h.get('count')} mean={mean:.6g} "
-                         f"min={h.get('min'):.6g} max={h.get('max'):.6g}")
 
     errors = [r for r in records
               if r.get("kind") == "event" and r.get("level") == "error"]

@@ -1,17 +1,20 @@
-"""Span helpers beyond the recorder's wall-clock spans.
+"""Span helpers beyond the recorder's host spans.
 
 The pipeline spans (``step/get_batch``, ``step/dispatch``,
 ``host/assemble``, ``host/place``, ``h2d/place_batch``,
 ``metrics/readback``, ``ckpt/save``) are instrumented strictly at host
-boundaries and close on wall clock — a ``step/dispatch`` span measures
-dispatch latency, NOT device compute (the sync-free loop never blocks
-on the step's outputs; device time keeps coming from the MetricsRing
-readback cadence and the run-level synchronized steps/sec).
+boundaries and close on the host clock — a ``step/dispatch`` span
+measures dispatch latency, NOT device compute (the sync-free loop never
+blocks on the step's outputs; device time keeps coming from the
+MetricsRing readback cadence and the run-level synchronized steps/sec).
 
 For deep dives where device-side timing IS wanted, ``ProfileWindow``
 arms an opt-in ``jax.profiler`` trace over a bounded step window; it is
 entirely inert unless a log directory is given, and fails the run when
-the trace it was given cannot be taken.
+the trace it was given cannot be taken. With a recorder enabled, every
+span also lands in that trace as a host annotation of the same name, on
+the clock of the device's events, beside the trainer's per-step
+``StepTraceAnnotation("train", step_num=i)`` markers.
 """
 from __future__ import annotations
 

@@ -20,12 +20,13 @@ here on the host mesh):
 Pipeline overlap: the loop itself never forces a device sync. Metrics
 stay on device in a small ring (`MetricsRing`) and are read back only at
 log boundaries and at the end of the run, with an explicit
-`block_until_ready` on just that entry; per-step wall times are recorded
-from the host side without blocking (they measure dispatch, not device
-compute — the run-level `steps_per_sec` is the synchronized number).
-With a prefetching loader (`repro.data.PrefetchLoader`) and a donated
-step (`core.mpsl.jit_train_step`), host batch assembly, H2D transfer,
-and device compute all overlap.
+`block_until_ready` on just that entry; the run-level `steps_per_sec` is
+the synchronized number. Each iteration runs inside a
+`jax.profiler.StepTraceAnnotation("train", step_num=i)`, the marker a
+profiler's step view reads (`--profile-dir`). With a prefetching loader
+(`repro.data.PrefetchLoader`) and a donated step
+(`core.mpsl.jit_train_step`), host batch assembly, H2D transfer, and
+device compute all overlap.
 """
 from __future__ import annotations
 
@@ -102,15 +103,14 @@ class Trainer:
         self.cfg = config
         self.log = log_fn
         # ambient recorder resolved at construction; pass one explicitly
-        # to pin a sink. All obs calls are host-side wall-clock only —
-        # the jitted program and its dispatch pattern are identical with
+        # to pin a sink. All obs calls are host-side only — the jitted
+        # program and its dispatch pattern are identical with
         # telemetry on or off (asserted in tests/test_pipeline.py).
         self.obs = recorder if recorder is not None else obs_mod.get()
         self.ckpt = (AsyncCheckpointer(config.ckpt_dir, config.keep)
                      if config.ckpt_dir else None)
         self.metrics_history: list = []
         self.ring = MetricsRing(config.metrics_ring)
-        self.step_times: list = []      # host dispatch time per step (s)
         self.skipped_steps: list = []   # non-finite guard skips (fault mode)
         self._skip_scan_from = 0        # ring high-water mark for the scan
         self._profile = ProfileWindow(config.profile_dir,
@@ -198,24 +198,21 @@ class Trainer:
         host_s = 0.0                    # time spent assembling/placing input
         for i in range(start, total):
             self._profile.on_step(i)
-            t_step = time.perf_counter()
-            with self.obs.span("step/get_batch", step=i):
-                batch = self.loader.batch(i)
-                batch = {k: jnp.asarray(v) for k, v in batch.items()}
-            t_in = time.perf_counter()
-            host_s += t_in - t_step
-            with self.obs.span("step/dispatch", step=i):
-                self.state, metrics = self.step_fn(self.state, batch)
-            self.ring.push(i + 1, metrics)
-            dt = time.perf_counter() - t_step
-            self.step_times.append(dt)
-            self.obs.observe("step/wall_s", dt)
-            if (i + 1) % self.cfg.log_every == 0 or i == start:
-                self._log_latest(total, t0)
-            if self.ckpt and (i + 1) % self.cfg.ckpt_every == 0:
-                with self.obs.span("ckpt/save", step=i + 1):
-                    self.ckpt.save(i + 1, self.state)
-                self.obs.counter("trainer/checkpoints")
+            with jax.profiler.StepTraceAnnotation("train", step_num=i):
+                t_step = time.perf_counter()
+                with self.obs.span("step/get_batch", step=i):
+                    batch = self.loader.batch(i)
+                    batch = {k: jnp.asarray(v) for k, v in batch.items()}
+                host_s += time.perf_counter() - t_step
+                with self.obs.span("step/dispatch", step=i):
+                    self.state, metrics = self.step_fn(self.state, batch)
+                self.ring.push(i + 1, metrics)
+                if (i + 1) % self.cfg.log_every == 0 or i == start:
+                    self._log_latest(total, t0)
+                if self.ckpt and (i + 1) % self.cfg.ckpt_every == 0:
+                    with self.obs.span("ckpt/save", step=i + 1):
+                        self.ckpt.save(i + 1, self.state)
+                    self.obs.counter("trainer/checkpoints")
         self._profile.stop()
         # final readback reflects the LAST step, not the last logged step
         with self.obs.span("metrics/readback"):
@@ -238,14 +235,13 @@ class Trainer:
                   "host_stall_frac": (host_s / wall) if wall > 0 else 0.0,
                   "skipped_steps": list(self.skipped_steps),
                   "wall_s": wall}
-        # close out the run log: link accounting captured at trace time,
-        # histogram aggregations, and the run summary
+        # close out the run log: link accounting captured at trace time
+        # and the run summary
         obs_mod.comm.emit_snapshot(self.obs)
         self.obs.event("trainer/run_end", steps=ran,
                        final_loss=result["final_loss"],
                        steps_per_sec=round(result["steps_per_sec"], 4),
                        host_stall_frac=round(result["host_stall_frac"], 4),
                        wall_s=round(wall, 4))
-        self.obs.emit_hists()
         self.obs.flush()
         return result

@@ -24,15 +24,46 @@ def cache_dir(tmp_path, monkeypatch):
     (and drop its cache handle) afterwards."""
     from jax.experimental.compilation_cache import compilation_cache as cc
     before = jax.config.jax_compilation_cache_dir
+    meta = jax.config.jax_compilation_cache_include_metadata_in_key
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jc"))
     yield tmp_path / "jc"
     jax.config.update("jax_compilation_cache_dir", before)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", meta)
     cc.reset_cache()
 
 
 def test_compile_cache_follows_env(cache_dir):
     assert compile_cache.enable_compilation_cache() == str(cache_dir)
     assert jax.config.jax_compilation_cache_dir == str(cache_dir)
+
+
+def test_compile_cache_keys_on_op_names(cache_dir):
+    """Programs that differ only in a named scope get cache entries of
+    their own, so a traced run never reads another tree's op names."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    names = ("jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    before = [getattr(jax.config, n) for n in names]
+
+    def scaled(scope):
+        def scaled_by_two(x):
+            with jax.named_scope(scope):
+                return x * 2.0
+        return scaled_by_two
+
+    try:
+        for n in names:
+            jax.config.update(n, 0)
+        cc.reset_cache()
+        compile_cache.enable_compilation_cache()
+        for scope in ("client_head", "optimizer", "client_head"):
+            jax.jit(scaled(scope)).lower(np.ones(4, np.float32)).compile()
+    finally:
+        for n, v in zip(names, before):
+            jax.config.update(n, v)
+    entries = [p for p in cache_dir.iterdir()
+               if p.name.startswith("jit_scaled_by_two")]
+    assert len(entries) == 2
 
 
 def test_compile_cache_defaults_to_checkout(cache_dir, monkeypatch):

@@ -33,8 +33,8 @@ def test_noop_default_is_inert():
     obs.event("x/e")
     obs.counter("x/c")
     obs.gauge("x/g", 1.0)
-    obs.observe("x/h", 0.5)
     assert obs.get() is obs.get()        # singleton
+    assert obs.span("x/a") is obs.span("x/b", step=2)
 
 
 def test_recorder_jsonl_roundtrip(tmp_path):
@@ -46,8 +46,8 @@ def test_recorder_jsonl_roundtrip(tmp_path):
         rec.counter("n/steps", 2)
         rec.counter("n/steps", 3)
         rec.gauge("q/depth", 4, step=3)
-        rec.observe("wall_s", 0.25)
-        rec.observe("wall_s", 0.75)
+        with rec.span("stage/a", step=4):
+            pass
         rec.event("boom", level="error", detail="x")
         # error events flush immediately (crash durability): visible
         # before close
@@ -63,10 +63,33 @@ def test_recorder_jsonl_roundtrip(tmp_path):
     span = by_kind["span"][0]
     assert span["name"] == "stage/a" and span["dur_s"] >= 0
     assert span["fields"] == {"step": 3}
+    assert [s["fields"]["step"] for s in by_kind["span"]] == [3, 4]
     assert by_kind["counter"][-1]["total"] == 5
-    hist = [h for h in by_kind["hist"] if h["name"] == "wall_s"][0]
-    assert hist["count"] == 2 and hist["sum"] == 1.0
-    assert hist["min"] == 0.25 and hist["max"] == 0.75
+    assert set(by_kind) == {"meta", "span", "counter", "gauge", "event"}
+
+
+def test_spans_reach_the_profiler_trace(tmp_path):
+    """An enabled recorder's span is also a host annotation in a
+    jax.profiler trace, inside the trainer's step marker; a disabled
+    recorder still hands out the one shared null span."""
+    assert obs.span("step/dispatch", step=0) is obs.span("metrics/readback")
+    logdir = tmp_path / "trace"
+    with obs.enabled(str(tmp_path / "log.jsonl")) as rec:
+        jax.profiler.start_trace(str(logdir))
+        try:
+            with jax.profiler.StepTraceAnnotation("train", step_num=7):
+                with rec.span("step/dispatch", step=7):
+                    jnp.ones(4).block_until_ready()
+        finally:
+            jax.profiler.stop_trace()
+    (path,) = logdir.glob("plugins/profile/*/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    host = {e.name.split("#")[0]: (e.start_ns, e.start_ns + e.duration_ns)
+            for p in data.planes if p.name.startswith("/host:")
+            for ln in p.lines for e in ln.events}
+    assert "step/dispatch" in host and "train" in host
+    (s0, e0), (s1, e1) = host["train"], host["step/dispatch"]
+    assert s0 <= s1 <= e1 <= e0
 
 
 def test_report_renders_tables():
@@ -282,8 +305,9 @@ def test_trainer_obs_end_to_end(tmp_path, monkeypatch):
     assert "downlink.gradients" in links
     gauges = {r["name"] for r in recs if r.get("kind") == "gauge"}
     assert "train/loss" in gauges and "prefetch/queue_depth" in gauges
-    hists = {r["name"] for r in recs if r.get("kind") == "hist"}
-    assert "step/wall_s" in hists
+    dispatched = [r["fields"]["step"] for r in recs
+                  if r.get("kind") == "span" and r["name"] == "step/dispatch"]
+    assert dispatched == list(range(steps))
     events = {r["name"] for r in recs if r.get("kind") == "event"}
     assert {"trainer/run_start", "trainer/run_end"} <= events
     rendered = report.render(recs)
