@@ -6,10 +6,12 @@ Kernel coverage (fused forward / fused backward):
 
   flash_attention — fwd + bwd. HBM->VMEM blocked online-softmax attention
                     (the body's dominant matmul pair at 4k-32k sequence
-                    lengths). The forward emits a per-row LSE residual;
-                    the backward's dq and dk/dv kernels recompute
-                    probabilities blockwise from (q, k, v, lse), so no
-                    [Sq, Sk] intermediate exists in either direction.
+                    lengths; attention's "auto" runs it on one TPU device
+                    for multi-token calls over at most 2048 keys). The
+                    forward emits a per-row LSE residual; the backward's
+                    dq and dk/dv kernels recompute probabilities
+                    blockwise from (q, k, v, lse), so no [Sq, Sk]
+                    intermediate exists in either direction.
                     Non-block-multiple sequence lengths are padded onto
                     the block grid with masked keys / zero-cotangent
                     query rows.

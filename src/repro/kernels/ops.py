@@ -9,18 +9,17 @@ Quant-dequant is straight-through.
 On this CPU container kernels execute in interpret mode; on TPU
 `interpret=False`.
 
-The key-validity mask is resolved ONCE at the public entry (`None` ->
-all-ones) and threaded through the VJP residuals, so forward and
-backward always see the identical mask — including under `jax.jit`
-where the mask is a traced array and could not ride along as a static
-nondiff argument.
+The key-validity mask (or None: every key valid, and no mask work in the
+kernels) is threaded through the VJP residuals, so forward and backward
+always see the identical mask — including under `jax.jit` where the
+mask is a traced array and could not ride along as a static nondiff
+argument.
 """
 from __future__ import annotations
 
 import functools
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels import flash_attention as _fa
 from repro.kernels import quant8 as _q8
@@ -38,8 +37,7 @@ INTERPRET = jax.default_backend() != "tpu"
 def flash_attention(q, k, v, q_pos, k_pos, causal=True, window=0,
                     k_valid=None, block_q=512, block_k=512):
     """Fused attention with a fused blockwise backward (see _fa module)."""
-    kv = k_valid if k_valid is not None else jnp.ones(k_pos.shape, bool)
-    return _flash_attention(q, k, v, q_pos, k_pos, kv, bool(causal),
+    return _flash_attention(q, k, v, q_pos, k_pos, k_valid, bool(causal),
                             int(window), int(block_q), int(block_k))
 
 
@@ -58,7 +56,7 @@ def _fa_fwd(q, k, v, q_pos, k_pos, k_valid, causal, window, block_q,
                                        window=window, k_valid=k_valid,
                                        block_q=block_q, block_k=block_k,
                                        return_lse=True, interpret=INTERPRET)
-    # residuals carry the RESOLVED mask: fwd/bwd agree by construction
+    # residuals carry the mask: fwd/bwd agree by construction
     return out, (q, k, v, q_pos, k_pos, k_valid, out, lse)
 
 

@@ -3,12 +3,15 @@ variants, causal / full / sliding-window masks, blockwise (flash-style)
 training path and KV-cache decode path.
 
 Three interchangeable implementations of the core softmax(QK^T)V:
-  * naive      — materializes scores; oracle + short sequences.
+  * naive      — materializes scores; the oracle, decode, and short
+                 sequences where the flash kernel does not run.
   * blockwise  — online-softmax scan over KV blocks, pure jnp. This is the
                  memory-efficient default for long sequences and mirrors
                  the structure of the Pallas flash kernel.
   * pallas     — the TPU flash kernel (repro.kernels); CPU-validated in
-                 interpret mode, selected explicitly.
+                 interpret mode. "auto" runs it on a single TPU device
+                 for multi-token calls over at most 2048 keys with no
+                 cache (`resolve_impl`).
 """
 from __future__ import annotations
 
@@ -19,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import layers
+from repro.obs import recorder as _rec
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -190,6 +194,28 @@ def _cache_insert(cache, k_new, v_new, positions):
 # Public entry
 
 
+def resolve_impl(impl, backend, sq, sk, *, has_cache, has_precomputed_kv,
+                 devices=1):
+    """The core implementation a call runs, from what it can observe.
+
+    An explicit impl is kept, except that single-token decode never scans
+    KV blocks: its scores are [B, H, 1, Sk], cheap to materialize, and a
+    seq-sharded cache is not resharded into blocks. "auto" picks
+    blockwise above 2048 keys, and the Pallas flash kernel for a
+    multi-token call with no cache on a single TPU device (a Mosaic
+    kernel cannot be partitioned over several); naive otherwise."""
+    if impl == "blockwise" and sq == 1:
+        return "naive"
+    if impl != "auto":
+        return impl
+    if sq > 1 and sk > 2048:
+        return "blockwise"
+    if (backend == "tpu" and devices == 1 and sq > 1 and not has_cache
+            and not has_precomputed_kv):
+        return "pallas"
+    return "naive"
+
+
 def apply_attention(params, x, cfg, *, positions, causal=True, window=0,
                     cache=None, impl="auto", cos_sin=None, block=1024,
                     kv_x=None, kv_positions=None, precomputed_kv=None,
@@ -276,10 +302,12 @@ def apply_attention(params, x, cfg, *, positions, causal=True, window=0,
         v_all = _sh.shard_act(v_all, ("batch", None, None, None))
 
     sk = k_all.shape[1]
-    if impl == "auto" or (q.shape[1] == 1 and impl == "blockwise"):
-        # single-token decode: scores are [B, H, 1, Sk] — materializing is
-        # cheap and avoids resharding a seq-sharded cache into KV blocks
-        impl = "blockwise" if sk > 2048 and q.shape[1] > 1 else "naive"
+    impl = resolve_impl(impl, jax.default_backend(), q.shape[1], sk,
+                        has_cache=cache is not None,
+                        has_precomputed_kv=precomputed_kv is not None,
+                        devices=jax.device_count())
+    _rec.get().event("attn/impl", impl=impl, sq=q.shape[1], sk=sk,
+                     causal=causal)
 
     if impl == "naive":
         bias = _mask_bias(flat_pos, k_pos, causal, window, k_valid)

@@ -19,6 +19,7 @@ from repro.kernels.ref import (flash_attention_ref, quant_dequant_ref,
                                selective_scan_ref, softmax_xent_ref)
 
 ATOL = 2e-4
+BF16_TOL = 5e-2  # bf16 inputs and cotangents: test_flash_backward_bf16's
 SS_ATOL = 1e-4   # fused scan adjoint vs reference VJP, fp32
 
 
@@ -36,27 +37,33 @@ def _qkv(key, b, sq, sk, h, kh, hd, dtype=jnp.float32):
 # flash attention backward
 
 
+def _case(*args, dtype=jnp.float32, slow=True, id=None):
+    """A case of the backward sweep, named as before the dtype joined."""
+    return pytest.param(*args, dtype, id=id or "-".join(map(str, args)),
+                        marks=[pytest.mark.slow] if slow else [])
+
+
 @pytest.mark.parametrize(
-    "b,sq,sk,h,kh,hd,bq,bk,causal,window",
+    "b,sq,sk,h,kh,hd,bq,bk,causal,window,dtype",
     [
-        (1, 128, 128, 4, 4, 32, 64, 64, True, 0),    # MHA causal
-        pytest.param(2, 128, 256, 8, 2, 64, 64, 128, True, 0,
-                     marks=pytest.mark.slow),   # GQA rectangular
-        pytest.param(1, 128, 128, 4, 2, 32, 64, 64, False, 0,
-                     marks=pytest.mark.slow),   # full attention
-        pytest.param(2, 64, 64, 2, 1, 128, 64, 64, True, 32,
-                     marks=pytest.mark.slow),   # MQA sliding window
-        pytest.param(1, 96, 96, 4, 2, 32, 64, 64, True, 0,
-                     marks=pytest.mark.slow),   # Sq % block != 0
-        pytest.param(1, 70, 130, 6, 3, 16, 64, 64, True, 33,
-                     marks=pytest.mark.slow),   # odd both axes + window
-        pytest.param(1, 200, 456, 4, 4, 32, 128, 128, False, 0,
-                     marks=pytest.mark.slow),   # odd, non-causal
+        _case(1, 128, 128, 4, 4, 32, 64, 64, True, 0,
+              slow=False),                 # MHA causal
+        _case(2, 128, 256, 8, 2, 64, 64, 128, True, 0),   # GQA rectangular
+        _case(1, 128, 128, 4, 2, 32, 64, 64, False, 0),   # full attention
+        _case(2, 64, 64, 2, 1, 128, 64, 64, True, 32),    # MQA sliding window
+        _case(1, 96, 96, 4, 2, 32, 64, 64, True, 0),      # Sq % block != 0
+        _case(1, 70, 130, 6, 3, 16, 64, 64, True, 33),    # odd + window
+        _case(1, 200, 456, 4, 4, 32, 128, 128, False, 0),  # odd, non-causal
+        # the ViT's class: bf16, no mask, one block spanning a sequence
+        # that is no multiple of 8, every head in one grid step
+        _case(2, 34, 34, 12, 12, 64, 512, 512, False, 0, dtype=jnp.bfloat16,
+              slow=False, id="vit-2-34-34-12-12-64-nomask-bf16"),
     ])
 def test_flash_backward_matches_ref_vjp(b, sq, sk, h, kh, hd, bq, bk,
-                                        causal, window):
+                                        causal, window, dtype):
     key = jax.random.PRNGKey(42)
-    q, k, v, qp, kp = _qkv(key, b, sq, sk, h, kh, hd)
+    q, k, v, qp, kp = _qkv(key, b, sq, sk, h, kh, hd, dtype)
+    tol = ATOL if dtype == jnp.float32 else BF16_TOL
 
     def f_ker(q, k, v):
         return ops.flash_attention(q, k, v, qp, kp, causal=causal,
@@ -68,12 +75,15 @@ def test_flash_backward_matches_ref_vjp(b, sq, sk, h, kh, hd, bq, bk,
 
     out_k, vjp_k = jax.vjp(f_ker, q, k, v)
     out_r, vjp_r = jax.vjp(f_ref, q, k, v)
-    np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
-                               atol=ATOL, rtol=ATOL)
-    g = jax.random.normal(jax.random.fold_in(key, 3), out_k.shape)
+    np.testing.assert_allclose(np.asarray(out_k, np.float32),
+                               np.asarray(out_r, np.float32),
+                               atol=tol, rtol=tol)
+    g = jax.random.normal(jax.random.fold_in(key, 3), out_k.shape, dtype)
     for name, dk_, dr_ in zip("dq dk dv".split(), vjp_k(g), vjp_r(g)):
-        np.testing.assert_allclose(np.asarray(dk_), np.asarray(dr_),
-                                   atol=ATOL, rtol=ATOL, err_msg=name)
+        assert dk_.dtype == dtype, name
+        np.testing.assert_allclose(np.asarray(dk_, np.float32),
+                                   np.asarray(dr_, np.float32),
+                                   atol=tol, rtol=tol, err_msg=name)
 
 
 @pytest.mark.slow

@@ -26,26 +26,34 @@ SS_TOL = {jnp.float32: 1e-5, jnp.bfloat16: 2e-2}
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize(
-    "b,sq,sk,h,kh,hd,bq,bk",
+    "b,sq,sk,h,kh,hd,bq,bk,causal",
     [
-        (1, 128, 128, 4, 4, 32, 64, 64),      # MHA square
-        pytest.param(2, 128, 256, 8, 2, 64, 64, 128,
-                     marks=pytest.mark.slow),  # GQA, rectangular
-        pytest.param(1, 256, 128, 6, 3, 16, 128, 64,
-                     marks=pytest.mark.slow),  # odd head count
-        pytest.param(2, 64, 64, 2, 1, 128, 64, 64,
-                     marks=pytest.mark.slow),  # MQA, wide head
+        pytest.param(1, 128, 128, 4, 4, 32, 64, 64, True,
+                     id="1-128-128-4-4-32-64-64"),     # MHA square
+        pytest.param(2, 128, 256, 8, 2, 64, 64, 128, True,
+                     marks=pytest.mark.slow,
+                     id="2-128-256-8-2-64-64-128"),    # GQA, rectangular
+        pytest.param(1, 256, 128, 6, 3, 16, 128, 64, True,
+                     marks=pytest.mark.slow,
+                     id="1-256-128-6-3-16-128-64"),    # odd head count
+        pytest.param(2, 64, 64, 2, 1, 128, 64, 64, True,
+                     marks=pytest.mark.slow,
+                     id="2-64-64-2-1-128-64-64"),      # MQA, wide head
+        # the ViT's class: no mask, one block spanning a sequence that is
+        # no multiple of 8, every head in one grid step
+        pytest.param(2, 34, 34, 12, 12, 64, 512, 512, False,
+                     id="vit-2-34-34-12-12-64-nomask"),
     ])
-def test_flash_vs_ref_shapes(b, sq, sk, h, kh, hd, bq, bk, dtype):
+def test_flash_vs_ref_shapes(b, sq, sk, h, kh, hd, bq, bk, causal, dtype):
     key = jax.random.PRNGKey(0)
     q = jax.random.normal(key, (b, sq, h, hd), dtype)
     k = jax.random.normal(jax.random.fold_in(key, 1), (b, sk, kh, hd), dtype)
     v = jax.random.normal(jax.random.fold_in(key, 2), (b, sk, kh, hd), dtype)
     qp = jnp.broadcast_to(jnp.arange(sq)[None], (b, sq)).astype(jnp.int32)
     kp = jnp.broadcast_to(jnp.arange(sk)[None], (b, sk)).astype(jnp.int32)
-    out = flash_attention_fwd(q, k, v, qp, kp, causal=True,
+    out = flash_attention_fwd(q, k, v, qp, kp, causal=causal,
                               block_q=bq, block_k=bk, interpret=True)
-    ref = flash_attention_ref(q, k, v, qp, kp, causal=True)
+    ref = flash_attention_ref(q, k, v, qp, kp, causal=causal)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                atol=TOL[dtype], rtol=TOL[dtype])

@@ -47,6 +47,52 @@ def test_pallas_impl_matches_naive():
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=2e-4)
 
 
+@pytest.mark.parametrize("impl,backend,sq,sk,cache,pre,devices,want", [
+    ("auto", "tpu", 274, 274, False, False, 1, "pallas"),  # the ViT trunk
+    ("auto", "cpu", 274, 274, False, False, 1, "naive"),
+    ("auto", "tpu", 4096, 4096, False, False, 1, "blockwise"),
+    ("auto", "tpu", 1, 4096, False, False, 1, "naive"),    # decode
+    ("auto", "tpu", 1, 4096, True, False, 1, "naive"),     # cached decode
+    ("auto", "tpu", 274, 274, True, False, 1, "naive"),    # cached prefill
+    ("auto", "tpu", 274, 1500, False, True, 1, "naive"),   # cross-attn KV
+    ("auto", "tpu", 274, 274, False, False, 4, "naive"),   # partitioned
+    ("naive", "tpu", 274, 274, False, False, 1, "naive"),
+    ("pallas", "cpu", 274, 274, True, False, 1, "pallas"),
+    ("blockwise", "tpu", 274, 274, False, False, 1, "blockwise"),
+    ("blockwise", "tpu", 1, 4096, True, False, 1, "naive"),
+])
+def test_resolve_impl(impl, backend, sq, sk, cache, pre, devices, want):
+    assert attention.resolve_impl(impl, backend, sq, sk, has_cache=cache,
+                                  has_precomputed_kv=pre,
+                                  devices=devices) == want
+
+
+def test_auto_attention_on_cpu_is_naive_and_recorded(tmp_path):
+    """Off the TPU, "auto" lowers to the same program as "naive", with the
+    recorder on or off; the recorder gets one trace-time event per call."""
+    import json
+    from repro import obs
+    cfg = _attn_cfg()
+    key = jax.random.PRNGKey(2)
+    p = attention.init_attention(key, cfg)
+    x = jax.random.normal(key, (2, 24, cfg.d_model))
+    pos = layers.positions_from_shape(2, 24)
+
+    def lowered(impl):
+        return jax.jit(lambda p, x: attention.apply_attention(
+            p, x, cfg, positions=pos, causal=False, impl=impl)[0]).lower(
+                p, x).as_text(debug_info=False)
+    naive = lowered("naive")
+    assert lowered("auto") == naive
+    log = tmp_path / "run.jsonl"
+    with obs.enabled(str(log)):
+        assert lowered("auto") == naive
+    events = [r for r in map(json.loads, log.read_text().splitlines())
+              if r.get("name") == "attn/impl"]
+    assert [e["fields"] for e in events] == [
+        {"impl": "naive", "sq": 24, "sk": 24, "causal": False}]
+
+
 def test_rope_relative_shift_invariance():
     """RoPE attention logits depend only on relative positions."""
     hd = 32

@@ -2,8 +2,9 @@
 
 Interpret mode (every other kernel test) cannot see the TPU's tiling and
 VMEM rules, so each kernel is compiled here, forward and backward, for a
-described (not attached) v5e chip: head_dim 128 and 64, vocab 256000 and
-32001, d_inner 8192. Nothing runs. The topology is described inside a
+described (not attached) v5e chip: head_dim 128 and 64 at 4096 tokens
+and the ViT-B/16 trunk's 274, vocab 256000 and 32001, d_inner 8192.
+Nothing runs. The topology is described inside a
 fixture, never at import, and the persistent compilation cache is off
 around these compiles (an entry written for a described chip cannot be
 read back without one).
@@ -53,26 +54,34 @@ def _compile(fn, one_chip, *shapes):
 
 BF, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
-# (heads, kv heads, head_dim, window): minitron-4b global layers and
-# hymba-1.5b's windowed layers
-FLASH = {"hd128": (32, 8, 128, 0), "hd64": (25, 5, 64, 1024)}
+# (heads, kv heads, head_dim, window, batch, seq, causal): minitron-4b
+# global layers and hymba-1.5b's windowed layers at 4096 tokens with a
+# key-validity mask; the ViT-B/16 trunk of the meta-transformer-b16 cell
+# (8 clients x 32 samples of 274 tokens), with no mask at all
+FLASH = {"hd128": (32, 8, 128, 0, 1, 4096, True),
+         "hd64": (25, 5, 64, 1024, 1, 4096, True),
+         "vit_b16": (12, 12, 64, 0, 256, 274, False)}
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 @pytest.mark.parametrize("case", sorted(FLASH))
 def test_flash_attention_compiles_for_v5e(one_chip, case, direction):
-    h, kh, hd, window = FLASH[case]
-    b, s = 1, 4096
+    h, kh, hd, window, b, s, causal = FLASH[case]
     q, kv = ((b, s, h, hd), BF), ((b, s, kh, hd), BF)
-    pos, valid = ((b, s), I32), ((b, s), jnp.bool_)
+    pos = ((b, s), I32)
+    masks = [((b, s), jnp.bool_)] if causal else []
+
+    def valid(m):
+        return m[0] if m else None
     if direction == "fwd":
-        _compile(lambda q, k, v, p, kv_: fa.flash_attention_fwd(
-            q, k, v, p, p, window=window, k_valid=kv_, return_lse=True),
-            one_chip, q, kv, kv, pos, valid)
+        _compile(lambda q, k, v, p, *m: fa.flash_attention_fwd(
+            q, k, v, p, p, causal=causal, window=window, k_valid=valid(m),
+            return_lse=True), one_chip, q, kv, kv, pos, *masks)
     else:
-        _compile(lambda q, k, v, p, kv_, o, lse, do: fa.flash_attention_bwd(
-            q, k, v, p, p, kv_, o, lse, do, window=window),
-            one_chip, q, kv, kv, pos, valid, q, ((b, h, s), F32), q)
+        _compile(lambda q, k, v, p, o, lse, do, *m: fa.flash_attention_bwd(
+            q, k, v, p, p, valid(m), o, lse, do, causal=causal,
+            window=window),
+            one_chip, q, kv, kv, pos, q, ((b, h, s), F32), q, *masks)
 
 
 # (d_model, vocab): minitron-4b's 256000-wide head, hymba-1.5b's 32001
