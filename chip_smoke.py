@@ -10,12 +10,14 @@ non-zero without a result line. Phases on one chip:
   (a) every Pallas kernel, compiled, fwd and VJP against kernels/ref.py
       at real widths (head_dim 128 / 64, vocab 256000 / 32001,
       d_inner 8192);
-  (b) hymba-1.5b at published widths (4 clients, seq 4096, 2 trainable
-      blocks, bf16) through the trainer for 8 steps, with a jax.profiler
-      trace of steps 5-6;
-  (c) one step from the same seeded initial state with the Pallas
-      attention and CE kernels, against (b)'s first step, which ran the
-      jnp defaults;
+  (b) hymba-1.5b as published (widths, 128 meta tokens, K/V-sharing
+      pairs; 4 clients, seq 4096, 2 trainable blocks, bf16) through the
+      trainer for 8 steps, with a jax.profiler trace of steps 5-6 and the
+      selective scan's core as each call site resolved it (`ssm/impl`);
+  (c) one step of the registry's hymba-1.5b (no meta tokens, so the
+      flash kernel can take every layer) with the Pallas attention and CE
+      kernels, against the same step from the same seeded initial state
+      with the defaults;
   (d) two steps with the int8 cut-layer compression on;
   (e) (b)'s 2-step trace must hold TPU device events.
 
@@ -274,9 +276,17 @@ def peak_bytes(dev) -> int:
 
 def phase_train(clock, devs):
     shutil.rmtree(TRACE_DIR, ignore_errors=True)
-    result = train_run(HYMBA + ["--profile-dir", TRACE_DIR])
+    os.makedirs(TRACE_DIR)
+    log = os.path.join(TRACE_DIR, "run.jsonl")
+    result = train_run(HYMBA + ["--published-mechanisms", "--profile-dir",
+                                TRACE_DIR, "--obs-log", log])
     secs, n = clock.take()
     losses = losses_of(result)
+    with open(log) as f:
+        scans = [r["fields"] for r in map(json.loads, f)
+                 if r.get("name") == "ssm/impl"]
+    print(f"  ssm/impl per traced call site: {scans}", flush=True)
+    check(bool(scans), "no ssm/impl event in the run log")
     print(f"  device_kind={devs[0].device_kind} compile_s={secs:.1f} "
           f"({n} programs) peak_bytes_in_use={peak_bytes(devs[0])}",
           flush=True)
@@ -293,14 +303,15 @@ def phase_train(clock, devs):
     return losses
 
 
-def phase_kernels_vs_jnp(clock, jnp_loss):
-    """The Pallas step against (b)'s first step: both start from the state
-    seeded by --seed and take the loader's batch 0."""
+def phase_kernels_vs_jnp(clock):
+    """The Pallas attention and CE step against the default one: both start
+    from the state seeded by --seed and take the loader's batch 0."""
     from repro.launch import mesh as mesh_lib
+    jnp_loss, _ = one_step(HYMBA, mesh_lib.make_host_mesh())
     lp, gp = one_step(HYMBA, mesh_lib.make_host_mesh(), attn_impl="pallas",
                       ce_impl="pallas")
     secs, n = clock.take()
-    print(f"  pallas loss={lp:.5f} grad_norm={gp:.5f} | jnp loss="
+    print(f"  pallas loss={lp:.5f} grad_norm={gp:.5f} | default loss="
           f"{jnp_loss:.5f} compile_s={secs:.1f} ({n} programs)", flush=True)
     check(math.isfinite(gp), f"pallas grad norm {gp}")
     check(abs(lp - jnp_loss) <= LOSS_RTOL * abs(jnp_loss),
@@ -410,12 +421,10 @@ def main(argv=None) -> int:
     if args.four_chip:
         phases = [("four-chip", lambda: phase_four_chip(clock, devs))]
     else:
-        losses = []
         phases = [
             ("a kernels", lambda: phase_kernels(clock)),
-            ("b train", lambda: losses.extend(phase_train(clock, devs))),
-            ("c pallas vs jnp", lambda: phase_kernels_vs_jnp(clock,
-                                                             losses[0])),
+            ("b train", lambda: phase_train(clock, devs)),
+            ("c pallas vs jnp", lambda: phase_kernels_vs_jnp(clock)),
             ("d compress", lambda: phase_compress(clock)),
             ("e trace", phase_trace),
         ]

@@ -48,6 +48,9 @@ PAPER_ARCHS["meta-transformer-b16"] = meta_transformer.CONFIG
 
 ARCHS = {**ASSIGNED_ARCHS, **PAPER_ARCHS}
 
+# ModelConfig fields of mechanisms that an arch's registry entry leaves off
+PUBLISHED_MECHANISMS = {"hymba-1.5b": hymba_1_5b.PUBLISHED}
+
 
 def get_config(arch_id: str) -> ModelConfig:
     try:
@@ -62,7 +65,7 @@ def list_archs():
 
 
 __all__ = [
-    "ARCHS", "ASSIGNED_ARCHS", "PAPER_ARCHS", "SHAPES",
+    "ARCHS", "ASSIGNED_ARCHS", "PAPER_ARCHS", "PUBLISHED_MECHANISMS", "SHAPES",
     "ModelConfig", "MoEConfig", "MPSLConfig", "RunConfig", "ShapeConfig",
     "SSMConfig", "cell_supported", "get_config", "list_archs", "reduced",
 ]

@@ -61,6 +61,14 @@ class ModelConfig:
     sliding_window: int = 0
     # indices of global-attention layers when sliding_window > 0
     global_layers: Tuple[int, ...] = ()
+    # Hymba (arXiv:2411.13676 Sec. 2): learned meta tokens prepended to
+    # every sequence at the trunk's input (0 = none); windowed layers keep
+    # them visible beside the window
+    meta_tokens: int = 0
+    # Hymba cross-layer KV sharing: pairs (i, i + 1) of consecutive local
+    # layers; layer i + 1 has no K/V projections and attends with layer
+    # i's K and V (() = every layer keeps its own)
+    kv_share_groups: Tuple[Tuple[int, ...], ...] = ()
     # encoder-decoder (Whisper): number of encoder layers (0 = decoder-only)
     encoder_layers: int = 0
     encoder_seq: int = 0            # fixed encoder seq (stub frontend frames)
@@ -180,8 +188,8 @@ class RunConfig:
     attn_block: int = 1024              # blockwise attention KV block
     moe_impl: str = "dense"             # dense | ragged | ep
     moe_capacity: float = 2.0           # EP per-expert capacity slack
-    ssm_impl: str = "jnp"               # jnp | pallas
-    ssm_chunk: int = 256                # selective-scan chunk length
+    ssm_impl: str = "auto"              # auto | jnp | pallas
+    ssm_chunk: int = 256                # selective-scan chunk length (most)
     # selective-scan backward lowering (pallas path only): 'fused' runs the
     # checkpointed-recompute adjoint kernel; 'recompute' falls back to
     # jax.vjp through the jnp reference (the pre-fusion oracle path)

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from repro.core import compression, fusion, losses, split
 from repro.models import layers, model as M, tokenizers as tok
 from repro.obs import comm as obs_comm
+from repro.obs import recorder as _rec
 from repro.optim import (adamw_init, adamw_update, apply_updates,
                          clip_by_global_norm)
 from repro.parallel import sharding
@@ -92,10 +93,7 @@ def _run_body(frozen, server, cfg, h, positions, impls, remat,
 
 
 def len_from_params(tree) -> int:
-    total = 0
-    for sp in tree["segments"]:
-        total += jax.tree_util.tree_leaves(sp)[0].shape[0]
-    return total
+    return sum(M.stacked_layers(sp) for sp in tree["segments"])
 
 
 def _segments_for(frozen, server, cfg):
@@ -117,8 +115,13 @@ def make_lm_loss(cfg, run):
     cdt = jnp.dtype(run.compute_dtype)
     impls = dict(run_impls(run))
     remat = run.remat != "none"
+    owners = M.kv_share_pairs(cfg)
 
     def loss_fn(trainable, frozen, batch, rng):
+        if owners:
+            _rec.get().event("hybrid/kv_share",
+                             layers=[i + 1 for i in owners],
+                             from_layers=owners)
         tokens = batch["tokens"]
         n, bn, s_text = tokens.shape
         r_up, r_down = jax.random.split(jax.random.fold_in(rng, 1))
@@ -145,6 +148,13 @@ def make_lm_loss(cfg, run):
 
         seq = h.shape[2]
         hb = h.reshape(n * bn, seq, cfg.d_model)
+        if cfg.meta_tokens:
+            # Hymba's meta tokens R (server-side, frozen): X~ = [R; X]
+            meta = frozen["meta_tokens"].astype(hb.dtype)
+            hb = jnp.concatenate(
+                [jnp.broadcast_to(meta[None], (n * bn,) + meta.shape), hb],
+                axis=1)
+            seq = hb.shape[1]
         hb = sharding.shard_act(hb, ("batch", None, None))
         positions = _build_positions(cfg, batch, n * bn, seq)
 

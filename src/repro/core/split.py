@@ -50,7 +50,8 @@ def resolve_trainable_blocks(cfg, mpsl) -> int:
 
 
 def split_segments(segs: List[M.Segment], boundary: int):
-    """Split a Segment list at a layer boundary (counted from layer 0)."""
+    """Split a Segment list at a layer boundary (counted from layer 0); a
+    K/V-sharing pair stays on one side."""
     frozen, train, seen = [], [], 0
     for seg in segs:
         if seen + seg.count <= boundary:
@@ -59,6 +60,9 @@ def split_segments(segs: List[M.Segment], boundary: int):
             train.append(seg)
         else:
             cut = boundary - seen
+            if seg.kind.kv_pair and cut % 2:
+                raise ValueError(f"the trainable boundary at layer "
+                                 f"{boundary} splits a K/V-sharing pair")
             frozen.append(M.Segment(seg.kind, cut))
             train.append(M.Segment(seg.kind, seg.count - cut))
         seen += seg.count
@@ -80,7 +84,7 @@ def _slice_stacked(seg_params_list, segs: List[M.Segment], boundary: int):
         elif seen >= boundary:
             train.append(sp)
         else:
-            cut = boundary - seen
+            cut = M.Segment(seg.kind, boundary - seen).steps
             frozen.append(jax.tree_util.tree_map(lambda a: a[:cut], sp))
             train.append(jax.tree_util.tree_map(lambda a: a[cut:], sp))
         seen += seg.count
@@ -138,6 +142,8 @@ def init_mpsl_lm(key, cfg, run):
         base["segments"], M.body_segments(cfg), plan.boundary)
 
     frozen: Dict[str, Any] = {"embed": base["embed"], "segments": fseg_p}
+    if "meta_tokens" in base:          # the server's, frozen with the trunk
+        frozen["meta_tokens"] = base["meta_tokens"]
     if "encoder" in base:
         frozen["encoder"] = base["encoder"]
     frozen = layers.cast_tree(frozen, jnp.dtype(run.frozen_dtype))
@@ -218,14 +224,14 @@ def assemble_full_params(params, frozen, plan, client_head=None):
         while remaining:
             if seen < plan.boundary:
                 src = fseg_p[fi]
-                n = jax.tree_util.tree_leaves(src)[0].shape[0]
+                n = M.stacked_layers(src)
                 take.append(src)
                 fi += 1
                 seen += n
                 remaining -= n
             else:
                 src = tseg_p[ti]
-                n = jax.tree_util.tree_leaves(src)[0].shape[0]
+                n = M.stacked_layers(src)
                 take.append(src)
                 ti += 1
                 seen += n
@@ -237,6 +243,8 @@ def assemble_full_params(params, frozen, plan, client_head=None):
            "final_norm": params["server"]["final_norm"]}
     if "embed" in frozen:
         out["embed"] = layers.cast_tree(frozen["embed"], jnp.float32)
+    if "meta_tokens" in frozen:
+        out["meta_tokens"] = frozen["meta_tokens"].astype(jnp.float32)
     if "encoder" in frozen:
         out["encoder"] = layers.cast_tree(frozen["encoder"], jnp.float32)
     if "lm_head" in params["server"]:

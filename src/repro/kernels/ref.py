@@ -8,8 +8,9 @@ NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
 def flash_attention_ref(q, k, v, q_pos, k_pos, *, causal=True, window=0,
-                        k_valid=None):
-    """q [B,Sq,H,hd], k/v [B,Sk,K,hd] (GQA), absolute-position masking.
+                        k_valid=None, prefix=0):
+    """q [B,Sq,H,hd], k/v [B,Sk,K,hd] (GQA), absolute-position masking;
+    with a window, keys at positions below `prefix` stay visible.
 
     Plain materialized-scores attention in f32."""
     b, sq, h, hd = q.shape
@@ -22,7 +23,8 @@ def flash_attention_ref(q, k, v, q_pos, k_pos, *, causal=True, window=0,
     if causal:
         ok &= k_pos[:, None, :] <= q_pos[:, :, None]
     if window:
-        ok &= (q_pos[:, :, None] - k_pos[:, None, :]) < window
+        ok &= (((q_pos[:, :, None] - k_pos[:, None, :]) < window)
+               | (k_pos[:, None, :] < prefix))
     if k_valid is not None:
         ok &= k_valid[:, None, :]
     s = jnp.where(ok[:, None, None, :, :], s, NEG_INF)

@@ -95,6 +95,24 @@ def _kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, h0_ref,   # inputs
         hout_ref[0] = h
 
 
+def fit_blocks(s: int, di: int, chunk: int = 256, block_d: int = 512):
+    """(chunk, block_d) that tile a scan of ``s`` positions over ``di``
+    channels with no padding, or None where none does.
+
+    ``block_d`` is kept where it divides ``di``; else the largest multiple
+    of 128 lanes up to 1024 that does (640 for hymba-1.5b's 3200). The
+    chunk is the largest multiple of 16 rows up to ``chunk`` that divides
+    ``s`` (192 for 4096 text positions after 128 meta tokens)."""
+    if di % block_d:
+        fits = [b for b in range(1024, 127, -128) if di % b == 0]
+        if not fits:
+            return None
+        block_d = fits[0]
+    fits = [c for c in range(min(chunk, s) // 16 * 16, 15, -16)
+            if s % c == 0]
+    return (fits[0], block_d) if fits else None
+
+
 def _resolve_blocks(s, di, chunk, block_d):
     block_d = min(block_d, di)
     chunk = min(chunk, s)

@@ -15,13 +15,15 @@ published widths in the RunConfig compute dtype (bfloat16).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import functools
 
 import jax
 import numpy as np
 
 from repro import faults, obs
-from repro.configs import (MPSLConfig, RunConfig, SHAPES, get_config, reduced)
+from repro.configs import (MPSLConfig, PUBLISHED_MECHANISMS, RunConfig,
+                           SHAPES, get_config, reduced)
 from repro.core import mpsl, split
 from repro.data import (ClientLoader, PrefetchLoader, SyntheticLM,
                         dirichlet_partition)
@@ -64,6 +66,10 @@ def parse_args(argv=None):
     p.add_argument("--trainable-blocks", type=int, default=-1)
     p.add_argument("--drop-prob", type=float, default=0.0)
     p.add_argument("--compress", action="store_true")
+    p.add_argument("--published-mechanisms", action="store_true",
+                   help="turn on what the arch's registry entry leaves "
+                        "off (hymba-1.5b: meta tokens and K/V-sharing "
+                        "pairs); with --full")
     p.add_argument("--ckpt-dir", default=None)
     p.add_argument("--ckpt-every", type=int, default=25)
     p.add_argument("--log-every", type=int, default=10,
@@ -97,6 +103,8 @@ def make_run_config(args, **overrides):
     """(model config, RunConfig) for parsed args; ``overrides`` replace
     RunConfig fields (e.g. ``attn_impl``)."""
     cfg = get_config(args.arch)
+    if args.published_mechanisms:
+        cfg = dataclasses.replace(cfg, **PUBLISHED_MECHANISMS[args.arch])
     if args.reduced:
         cfg = reduced(cfg)
         overrides.setdefault("compute_dtype", "float32")

@@ -54,16 +54,22 @@ def init_attention(key, cfg):
 # Masks
 
 
-def _mask_bias(q_pos, k_pos, causal: bool, window: int, k_valid=None):
+def _mask_bias(q_pos, k_pos, causal: bool, window: int, k_valid=None,
+               prefix: int = 0):
     """Additive bias [B, Sq, Sk] from absolute positions.
 
     q_pos [B, Sq], k_pos [B, Sk]; window > 0 keeps keys with
-    q_pos - k_pos < window. k_valid optionally marks populated KV slots."""
+    q_pos - k_pos < window, and also (prefix > 0) the keys at positions
+    below `prefix` (Hymba's meta tokens). k_valid optionally marks
+    populated KV slots."""
     ok = jnp.ones(q_pos.shape[:1] + (q_pos.shape[1], k_pos.shape[1]), bool)
     if causal:
         ok &= k_pos[:, None, :] <= q_pos[:, :, None]
     if window and window > 0:
-        ok &= (q_pos[:, :, None] - k_pos[:, None, :]) < window
+        near = (q_pos[:, :, None] - k_pos[:, None, :]) < window
+        if prefix:
+            near |= k_pos[:, None, :] < prefix
+        ok &= near
     if k_valid is not None:
         ok &= k_valid[:, None, :]
     return jnp.where(ok, 0.0, NEG_INF).astype(jnp.float32)
@@ -88,7 +94,7 @@ def _naive_attention(q, k, v, bias):
 
 
 def _blockwise_attention(q, k, v, q_pos, k_pos, causal, window,
-                         k_valid=None, block: int = 1024):
+                         k_valid=None, block: int = 1024, prefix: int = 0):
     """Online-softmax scan over KV blocks. Memory O(Sq * block)."""
     b, sq, h, hd = q.shape
     kh = k.shape[2]
@@ -128,7 +134,7 @@ def _blockwise_attention(q, k, v, q_pos, k_pos, causal, window,
         kc, vc, pc, vm = blk
         s = jnp.einsum("bqkgd,bskd->bqkgs", qg, kc,
                        preferred_element_type=jnp.float32)
-        bias = _mask_bias(q_pos, pc, causal, window, vm)
+        bias = _mask_bias(q_pos, pc, causal, window, vm, prefix)
         s = s + bias[:, :, None, None, :]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[..., None])
@@ -195,15 +201,16 @@ def _cache_insert(cache, k_new, v_new, positions):
 
 
 def resolve_impl(impl, backend, sq, sk, *, has_cache, has_precomputed_kv,
-                 devices=1):
+                 devices=1, prefix=0):
     """The core implementation a call runs, from what it can observe.
 
     An explicit impl is kept, except that single-token decode never scans
     KV blocks: its scores are [B, H, 1, Sk], cheap to materialize, and a
     seq-sharded cache is not resharded into blocks. "auto" picks
     blockwise above 2048 keys, and the Pallas flash kernel for a
-    multi-token call with no cache on a single TPU device (a Mosaic
-    kernel cannot be partitioned over several); naive otherwise."""
+    multi-token call with no cache and no visible prefix on a single TPU
+    device (a Mosaic kernel cannot be partitioned over several, and the
+    kernel's mask has no prefix); naive otherwise."""
     if impl == "blockwise" and sq == 1:
         return "naive"
     if impl != "auto":
@@ -211,7 +218,7 @@ def resolve_impl(impl, backend, sq, sk, *, has_cache, has_precomputed_kv,
     if sq > 1 and sk > 2048:
         return "blockwise"
     if (backend == "tpu" and devices == 1 and sq > 1 and not has_cache
-            and not has_precomputed_kv):
+            and not has_precomputed_kv and not prefix):
         return "pallas"
     return "naive"
 
@@ -219,13 +226,17 @@ def resolve_impl(impl, backend, sq, sk, *, has_cache, has_precomputed_kv,
 def apply_attention(params, x, cfg, *, positions, causal=True, window=0,
                     cache=None, impl="auto", cos_sin=None, block=1024,
                     kv_x=None, kv_positions=None, precomputed_kv=None,
-                    use_rope=None, seq_shard=False):
-    """x [B, S, D] -> (out [B, S, D], new_cache).
+                    use_rope=None, seq_shard=False, prefix=0,
+                    return_kv=False):
+    """x [B, S, D] -> (out [B, S, D], new_cache), and with `return_kv` the
+    K/V attended, as {'k', 'v', 'pos'} (after RoPE).
 
     positions: [B, S] absolute positions (or [B, 3, S] for M-RoPE).
     cache: None for train/prefill-without-cache, else KV cache dict.
     kv_x / kv_positions: cross-attention source (keys/values from encoder).
-    precomputed_kv: {'k','v','pos'} — decode-time cross-attention KV.
+    precomputed_kv: {'k','v','pos'} — decode-time cross-attention KV, or
+      (Hymba) the K/V that the first layer of a sharing pair computed.
+    prefix: with a window, keys at positions below it stay visible.
     seq_shard: shard the QUERY sequence over the TP axis for the core
       attention math (beyond-paper optimization for archs whose head count
       doesn't divide the TP width — without it every TP shard redundantly
@@ -305,17 +316,20 @@ def apply_attention(params, x, cfg, *, positions, causal=True, window=0,
     impl = resolve_impl(impl, jax.default_backend(), q.shape[1], sk,
                         has_cache=cache is not None,
                         has_precomputed_kv=precomputed_kv is not None,
-                        devices=jax.device_count())
+                        devices=jax.device_count(), prefix=prefix)
     _rec.get().event("attn/impl", impl=impl, sq=q.shape[1], sk=sk,
-                     causal=causal)
+                     causal=causal, prefix=prefix)
 
     if impl == "naive":
-        bias = _mask_bias(flat_pos, k_pos, causal, window, k_valid)
+        bias = _mask_bias(flat_pos, k_pos, causal, window, k_valid, prefix)
         out = _naive_attention(q, k_all, v_all, bias)
     elif impl == "blockwise":
         out = _blockwise_attention(q, k_all, v_all, flat_pos, k_pos,
-                                   causal, window, k_valid, block=block)
+                                   causal, window, k_valid, block=block,
+                                   prefix=prefix)
     elif impl == "pallas":
+        if prefix and window:
+            raise ValueError("the flash kernel has no visible prefix")
         from repro.kernels import ops as kops
         out = kops.flash_attention(q, k_all, v_all, flat_pos, k_pos,
                                    causal=causal, window=window,
@@ -324,6 +338,8 @@ def apply_attention(params, x, cfg, *, positions, causal=True, window=0,
         raise ValueError(f"unknown attention impl {impl!r}")
 
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(x.dtype))
+    if return_kv:
+        return y, cache, {"k": k_all, "v": v_all, "pos": k_pos}
     return y, cache
 
 

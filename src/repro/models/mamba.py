@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import layers
+from repro.obs import recorder as _rec
 
 
 def init_mamba(key, cfg, d_model: Optional[int] = None):
@@ -115,6 +116,30 @@ def selective_scan_step(x, dt, b_in, c_in, a_log, h):
     return y.astype(x.dtype), h_new
 
 
+def resolve_ssm_impl(impl, backend, s, d_inner, *, has_cache, devices=1,
+                     chunk=256):
+    """(core, chunk, block_d) of a multi-token scan, from what it can
+    observe, as attention's `resolve_impl` does.
+
+    "auto" runs the Pallas scan on a single TPU device (a Mosaic kernel
+    cannot be partitioned over several) for a cache-free call whose
+    positions and channels the kernel's blocks tile
+    (`selective_scan.fit_blocks`), and the jnp scan otherwise. An explicit
+    "pallas" keeps the kernel's default blocks where none fit. block_d is
+    None for the jnp core."""
+    from repro.kernels import selective_scan as kss
+    if impl == "auto":
+        fits = kss.fit_blocks(s, d_inner, chunk)
+        if (backend == "tpu" and devices == 1 and not has_cache
+                and fits is not None):
+            return ("pallas",) + fits
+        return "jnp", chunk, None
+    if impl == "pallas":
+        return ("pallas",) + (kss.fit_blocks(s, d_inner, chunk)
+                              or (chunk, 512))
+    return impl, chunk, None
+
+
 # ---------------------------------------------------------------------------
 # Cache
 
@@ -145,7 +170,7 @@ def _causal_depthwise_conv(x, w, b):
     return out + b.astype(x.dtype)
 
 
-def apply_mamba(params, x, cfg, cache=None, impl="jnp", chunk=256,
+def apply_mamba(params, x, cfg, cache=None, impl="auto", chunk=256,
                 bwd_impl="fused"):
     """x [B, S, D] -> (y [B, S, D], new_cache)."""
     d = x.shape[-1]
@@ -179,15 +204,21 @@ def apply_mamba(params, x, cfg, cache=None, impl="jnp", chunk=256,
     if cache is None or xc.shape[1] > 1:
         # train / prefill: chunked scan (optionally carrying a prior state)
         h0 = cache["h"] if cache is not None else None
-        if impl == "pallas":
-            from repro.kernels import ops as kops
-            y, h_final = kops.selective_scan(xc, dt, b_in, c_in,
-                                             params["A_log"], h0=h0,
-                                             chunk=chunk, bwd=bwd_impl)
-        else:
-            y, h_final = chunked_selective_scan(xc, dt, b_in, c_in,
-                                                params["A_log"], h0=h0,
-                                                chunk=chunk)
+        impl, chunk, block_d = resolve_ssm_impl(
+            impl, jax.default_backend(), xc.shape[1], di,
+            has_cache=cache is not None, devices=jax.device_count(),
+            chunk=chunk)
+        _rec.get().event("ssm/impl", impl=impl, s=xc.shape[1], d_inner=di,
+                         chunk=chunk, block_d=block_d)
+        with jax.named_scope("ssm_scan"):
+            if impl == "pallas":
+                from repro.kernels import ops as kops
+                y, h_final = kops.selective_scan(
+                    xc, dt, b_in, c_in, params["A_log"], h0, chunk, block_d,
+                    bwd_impl)
+            else:
+                y, h_final = chunked_selective_scan(
+                    xc, dt, b_in, c_in, params["A_log"], h0=h0, chunk=chunk)
         new_cache = None if cache is None else \
             {"h": h_final, "conv": new_conv}
     else:

@@ -4,9 +4,10 @@ The transformer body is represented as a list of SEGMENTS — runs of
 consecutive layers with identical static structure — each stored as a
 stacked pytree (leading layer axis) and executed with jax.lax.scan.
 Homogeneous archs have one segment; Hymba splits at its global-attention
-layers; Whisper has separate encoder and decoder stacks. Scan-over-layers
-keeps compile time flat in depth (94-layer qwen3 compiles like 2 layers)
-and jax.checkpoint around the scanned step gives per-block remat.
+layers and scans a K/V-sharing pair of layers as one step; Whisper has
+separate encoder and decoder stacks. Scan-over-layers keeps compile time
+flat in depth (94-layer qwen3 compiles like 2 layers) and jax.checkpoint
+around the scanned step gives per-block remat.
 """
 from __future__ import annotations
 
@@ -27,6 +28,8 @@ class BlockKind:
     is_global: bool = True      # full vs sliding-window attention
     causal: bool = True
     cross: bool = False         # cross-attention (whisper decoder)
+    kv_pair: bool = False       # two hybrid layers, the second attending
+                                # with the first's K/V (one scan step)
 
     @property
     def has_attn(self) -> bool:
@@ -40,7 +43,37 @@ class BlockKind:
 @dataclasses.dataclass(frozen=True)
 class Segment:
     kind: BlockKind
-    count: int
+    count: int                  # layers
+
+    @property
+    def steps(self) -> int:
+        """Scan steps: one per layer, or per K/V-sharing pair."""
+        return self.count // 2 if self.kind.kv_pair else self.count
+
+
+def stacked_layers(seg_params) -> int:
+    """Layers in a stacked segment's params (a K/V-sharing pair's step
+    holds two, under "first" and "second")."""
+    n = jax.tree_util.tree_leaves(seg_params)[0].shape[0]
+    return 2 * n if "first" in seg_params else n
+
+
+def kv_share_pairs(cfg) -> List[int]:
+    """First layers of cfg.kv_share_groups' pairs, checked: each group is
+    one local layer, or two consecutive ones."""
+    glb = set(cfg.global_layers)
+    firsts = []
+    for g in cfg.kv_share_groups:
+        g = tuple(g)
+        if len(g) not in (1, 2) or any(i in glb or not 0 <= i <
+                                       cfg.num_layers for i in g):
+            raise ValueError(f"a K/V-sharing group is one local layer or two "
+                             f"consecutive ones: {g}")
+        if len(g) == 2:
+            if g[1] != g[0] + 1:
+                raise ValueError(f"K/V-sharing pair {g} is not consecutive")
+            firsts.append(g[0])
+    return firsts
 
 
 def body_segments(cfg) -> List[Segment]:
@@ -55,13 +88,16 @@ def body_segments(cfg) -> List[Segment]:
     if fam == "hybrid":
         segs, i = [], 0
         glb = set(cfg.global_layers)
+        pairs = set(kv_share_pairs(cfg))
         while i < cfg.num_layers:
-            g = i in glb
-            j = i
-            while j < cfg.num_layers and (j in glb) == g:
-                j += 1
-            segs.append(Segment(BlockKind("hybrid", is_global=g), j - i))
-            i = j
+            kind = BlockKind("hybrid", is_global=i in glb,
+                             kv_pair=i in pairs)
+            n = 2 if kind.kv_pair else 1
+            if segs and segs[-1].kind == kind:
+                segs[-1] = Segment(kind, segs[-1].count + n)
+            else:
+                segs.append(Segment(kind, n))
+            i += n
         return segs
     if fam == "vit":
         return [Segment(BlockKind("vit", causal=False), cfg.num_layers)]
@@ -80,14 +116,19 @@ def encoder_segments(cfg) -> List[Segment]:
 # Block init / apply
 
 
-def init_block(key, cfg, kind: BlockKind):
+def init_block(key, cfg, kind: BlockKind, own_kv: bool = True):
+    if kind.kv_pair:
+        one = dataclasses.replace(kind, kv_pair=False)
+        k1, k2 = jax.random.split(key)
+        return {"first": init_block(k1, cfg, one),
+                "second": init_block(k2, cfg, one, own_kv=False)}
     ks = jax.random.split(key, 6)
     p: Dict[str, Any] = {"norm1": layers.init_norm(cfg.norm, cfg.d_model)}
     if kind.family == "ssm":
         p["ssm"] = mamba.init_mamba(ks[0], cfg)
         return p
     if kind.family == "hybrid":
-        p["mix"] = hybrid.init_hybrid(ks[0], cfg)
+        p["mix"] = hybrid.init_hybrid(ks[0], cfg, own_kv)
     else:
         p["attn"] = attention.init_attention(ks[0], cfg)
     if kind.cross:
@@ -103,27 +144,49 @@ def init_block(key, cfg, kind: BlockKind):
 
 def apply_block(params, x, cfg, kind: BlockKind, *, positions, cache=None,
                 enc_out=None, cross_kv=None, impls=None):
-    """One transformer block. Returns (x, new_cache, aux_loss)."""
+    """One transformer block, or a K/V-sharing pair of hybrid blocks.
+    Returns (x, new_cache, aux_loss)."""
+    if kind.kv_pair:
+        if cache is not None:
+            raise NotImplementedError("decoding through a K/V-sharing pair")
+        one = dataclasses.replace(kind, kv_pair=False)
+        x, _, a1, kv = _apply_block(params["first"], x, cfg, one,
+                                    positions=positions, impls=impls)
+        x, _, a2, _ = _apply_block(params["second"], x, cfg, one,
+                                   positions=positions, impls=impls,
+                                   shared_kv=kv)
+        return x, None, a1 + a2
+    return _apply_block(params, x, cfg, kind, positions=positions,
+                        cache=cache, enc_out=enc_out, cross_kv=cross_kv,
+                        impls=impls)[:3]
+
+
+def _apply_block(params, x, cfg, kind: BlockKind, *, positions, cache=None,
+                 enc_out=None, cross_kv=None, impls=None, shared_kv=None):
+    """One block: (x, new_cache, aux_loss, the K/V a hybrid block attended
+    with, or None)."""
     impls = impls or {}
     aux = jnp.zeros((), jnp.float32)
     h = layers.apply_norm(x, params["norm1"], cfg.norm)
 
-    new_cache = None
+    new_cache = kv = None
     if kind.family == "ssm":
         with jax.named_scope("ssm"):
             out, new_cache = mamba.apply_mamba(
                 params["ssm"], h, cfg, cache=cache,
-                impl=impls.get("ssm", "jnp"),
+                impl=impls.get("ssm", "auto"),
                 chunk=impls.get("ssm_chunk", 256),
                 bwd_impl=impls.get("ssm_bwd", "fused"))
-        return x + out, new_cache, aux
+        return x + out, new_cache, aux, None
     if kind.family == "hybrid":
-        out, new_cache = hybrid.apply_hybrid(
+        out, new_cache, kv = hybrid.apply_hybrid(
             params["mix"], h, cfg, positions=positions,
             is_global=kind.is_global, cache=cache,
-            impl=impls.get("attn", "auto"), ssm_impl=impls.get("ssm", "jnp"),
+            impl=impls.get("attn", "auto"), ssm_impl=impls.get("ssm", "auto"),
             ssm_bwd=impls.get("ssm_bwd", "fused"),
-            seq_shard=impls.get("attn_seq_shard", False))
+            ssm_chunk=impls.get("ssm_chunk", 256),
+            seq_shard=impls.get("attn_seq_shard", False),
+            shared_kv=shared_kv)
         x = x + out
     else:
         window = 0 if kind.is_global else cfg.sliding_window
@@ -158,7 +221,7 @@ def apply_block(params, x, cfg, kind: BlockKind, *, positions, cache=None,
         out = mlp.apply_mlp(params["mlp"], h, cfg.activation)
     x = x + out
     x = sharding.shard_act(x, impls.get("act_dims", ("batch", None, None)))
-    return x, new_cache, aux
+    return x, new_cache, aux, kv
 
 
 # ---------------------------------------------------------------------------
@@ -166,13 +229,15 @@ def apply_block(params, x, cfg, kind: BlockKind, *, positions, cache=None,
 
 
 def init_segment(key, cfg, seg: Segment):
-    keys = jax.random.split(key, seg.count)
+    keys = jax.random.split(key, seg.steps)
     return jax.vmap(lambda k: init_block(k, cfg, seg.kind))(keys)
 
 
 def init_segment_cache(cfg, seg: Segment, batch: int, cache_len: int,
                        dtype=jnp.bfloat16):
     kind = seg.kind
+    if kind.kv_pair:
+        raise NotImplementedError("a cache for a K/V-sharing pair")
     if kind.family == "ssm":
         return mamba.init_mamba_cache(cfg, batch, layer_count=seg.count,
                                       dtype=dtype)
@@ -265,6 +330,10 @@ def init_lm(key, cfg):
             ks[3], (cfg.d_model, cfg.vocab_size))
 
     enc_segs = encoder_segments(cfg)
+    if cfg.meta_tokens:
+        params["meta_tokens"] = layers.dense_init(
+            ks[6], (cfg.meta_tokens, cfg.d_model), in_axis_size=cfg.d_model)
+
     if enc_segs:
         ek = jax.random.split(ks[4], len(enc_segs))
         params["encoder"] = {
@@ -304,7 +373,11 @@ def run_encoder(params, frame_embeds, cfg, impls=None, remat=True):
 
 def forward_body(params, h, cfg, *, positions, cache=None, enc_out=None,
                  cross_kv=None, impls=None, remat=True):
-    """Embeddings -> final hidden states. Returns (h, new_caches, aux)."""
+    """Embeddings -> final hidden states. Returns (h, new_caches, aux).
+
+    Meta tokens are prepended by the MPSL LM loss only."""
+    if cfg.meta_tokens:
+        raise NotImplementedError("forward_body with meta tokens")
     segs = body_segments(cfg)
     aux_total = jnp.zeros((), jnp.float32)
     new_caches: Optional[List[Any]] = [] if cache is not None else None
@@ -382,7 +455,18 @@ def _norm_params(cfg) -> int:
     return cfg.d_model * (2 if cfg.norm == "layernorm" else 1)
 
 
+def _kv_params(cfg) -> int:
+    d, k, hd = cfg.d_model, cfg.num_kv_heads, cfg.resolved_head_dim
+    return 2 * d * k * hd + (2 * k * hd if cfg.qkv_bias else 0) + \
+        (hd if cfg.qk_norm else 0)
+
+
 def _block_params(cfg, kind: BlockKind) -> int:
+    """Parameters of one layer (a K/V-sharing pair's mean, as its second
+    layer has no K/V projections)."""
+    if kind.kv_pair:
+        one = dataclasses.replace(kind, kv_pair=False)
+        return _block_params(cfg, one) - _kv_params(cfg) // 2
     n = _norm_params(cfg)
     if kind.family == "ssm":
         return n + _mamba_params(cfg)
@@ -424,7 +508,7 @@ def count_params_analytic(cfg, trainable_blocks: Optional[int] = None) -> int:
     total += cfg.vocab_size * cfg.d_model           # embed
     if cfg.pos_embed == "learned":
         total += cfg.max_seq * cfg.d_model
-    total += _norm_params(cfg)
+    total += _norm_params(cfg) + cfg.meta_tokens * cfg.d_model
     if not cfg.tie_embeddings:
         total += cfg.d_model * cfg.vocab_size
     if cfg.encoder_layers:

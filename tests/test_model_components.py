@@ -90,7 +90,7 @@ def test_auto_attention_on_cpu_is_naive_and_recorded(tmp_path):
     events = [r for r in map(json.loads, log.read_text().splitlines())
               if r.get("name") == "attn/impl"]
     assert [e["fields"] for e in events] == [
-        {"impl": "naive", "sq": 24, "sk": 24, "causal": False}]
+        {"impl": "naive", "sq": 24, "sk": 24, "causal": False, "prefix": 0}]
 
 
 def test_rope_relative_shift_invariance():

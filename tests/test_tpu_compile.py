@@ -3,7 +3,8 @@
 Interpret mode (every other kernel test) cannot see the TPU's tiling and
 VMEM rules, so each kernel is compiled here, forward and backward, for a
 described (not attached) v5e chip: head_dim 128 and 64 at 4096 tokens
-and the ViT-B/16 trunk's 274, vocab 256000 and 32001, d_inner 8192.
+and the ViT-B/16 trunk's 274, vocab 256000 and 32001, d_inner 8192 and
+hymba-1.5b's 3200 at 4224 positions.
 Nothing runs. The topology is described inside a
 fixture, never at import, and the persistent compilation cache is off
 around these compiles (an entry written for a described chip cannot be
@@ -105,19 +106,28 @@ def test_softmax_xent_compiles_for_v5e(one_chip, monkeypatch, case,
     _compile(fn, one_chip, ((t, d), BF), ((d, v), F32), ((t,), I32))
 
 
-@pytest.mark.parametrize("direction", ["fwd", "bwd"])
-def test_selective_scan_compiles_for_v5e(one_chip, direction):
-    # falcon-mamba-7b's d_inner
-    b, s, di, ds, chunk = 1, 2048, 8192, 16, 256
+# (batch, positions, d_inner): falcon-mamba-7b's d_inner 8192, and the
+# hymba_full_lm_4k cell's scans (4 clients, 128 meta + 4096 text
+# positions, d_inner 3200), each with the blocks `fit_blocks` chooses
+SCAN = {"d_inner8192": (1, 2048, 8192), "hymba_full": (4, 4224, 3200)}
+
+
+@pytest.mark.parametrize("case,direction", [
+    pytest.param(c, d, id=d if c == "d_inner8192" else f"{c}-{d}")
+    for c in sorted(SCAN) for d in ("fwd", "bwd")])
+def test_selective_scan_compiles_for_v5e(one_chip, case, direction):
+    b, s, di = SCAN[case]
+    ds = 16
+    chunk, block_d = ss.fit_blocks(s, di)
     x, bc, a = ((b, s, di), BF), ((b, s, ds), BF), ((di, ds), F32)
     if direction == "fwd":
         _compile(lambda x, dt, b_, c, a: ss.selective_scan_fwd(
-            x, dt, b_, c, a, chunk=chunk, return_ckpt=True),
-            one_chip, x, x, bc, bc, a)
+            x, dt, b_, c, a, chunk=chunk, block_d=block_d,
+            return_ckpt=True), one_chip, x, x, bc, bc, a)
     else:
         ckpt = ((b, s // chunk, ds, di), F32)
         _compile(lambda x, dt, b_, c, a, hk, gy, gh: ss.selective_scan_bwd(
-            x, dt, b_, c, a, hk, gy, gh, chunk=chunk),
+            x, dt, b_, c, a, hk, gy, gh, chunk=chunk, block_d=block_d),
             one_chip, x, x, bc, bc, a, ckpt, x, ((b, di, ds), F32))
 
 
