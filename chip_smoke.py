@@ -8,8 +8,8 @@ a TPU and that the Pallas kernels will run compiled; otherwise it exits
 non-zero without a result line. Phases on one chip:
 
   (a) every Pallas kernel, compiled, fwd and VJP against kernels/ref.py
-      at real widths (head_dim 128 / 64, vocab 256000 / 32001,
-      d_inner 8192);
+      at real widths (head_dim 128 / 64, hymba-1.5b's 4224 positions
+      with its meta prefix, vocab 256000 / 32001, d_inner 8192);
   (b) hymba-1.5b as published (widths, 128 meta tokens, K/V-sharing
       pairs; 4 clients, seq 4096, 2 trainable blocks, bf16) through the
       trainer for 8 steps, with a jax.profiler trace of steps 5-6 and the
@@ -155,21 +155,28 @@ def phase_kernels(clock):
             theirs = run(g)
         return mine, theirs
 
-    # flash attention: minitron-4b's head_dim 128 (GQA 32/8, global) and
-    # hymba-1.5b's head_dim 64 (GQA 25/5, 1024-token window)
-    for h, kh, hd, window in [(32, 8, 128, 0), (25, 5, 64, 1024)]:
-        b, s = 1, 2048
+    # flash attention: minitron-4b's head_dim 128 (GQA 32/8, global),
+    # hymba-1.5b's head_dim 64 (GQA 25/5, 1024-token window), and hymba as
+    # published: 4224 positions whose first 128 (the meta tokens) stay in
+    # every window's view, so the kernels skip most tiles
+    for h, kh, hd, window, s, prefix in [(32, 8, 128, 0, 2048, 0),
+                                         (25, 5, 64, 1024, 2048, 0),
+                                         (25, 5, 64, 1024, 4224, 128)]:
+        b = 1
         q, k, v = (normal(1, (b, s, h, hd)), normal(2, (b, s, kh, hd)),
                    normal(3, (b, s, kh, hd)))
         pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
         ct = normal(4, (b, s, h, hd))
         mine, theirs = vjp_pair(
             lambda q, k, v: ops.flash_attention(
-                q, k, v, pos, pos, causal=True, window=window),
+                q, k, v, pos, pos, causal=True, window=window,
+                prefix=prefix),
             lambda q, k, v: ref.flash_attention_ref(
-                q, k, v, pos, pos, causal=True, window=window),
+                q, k, v, pos, pos, causal=True, window=window,
+                prefix=prefix),
             (q, k, v), ct)
-        compare(f"flash_attention hd={hd} window={window}", mine, theirs)
+        compare(f"flash_attention hd={hd} window={window} s={s} "
+                f"prefix={prefix}", mine, theirs)
 
     # fused LM-head CE: vocab 256000 at d_model 3072, vocab 32001 at 1600
     for t, d, vocab in [(1024, 3072, 256_000), (4096, 1600, 32_001)]:

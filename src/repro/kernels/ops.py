@@ -35,36 +35,39 @@ INTERPRET = jax.default_backend() != "tpu"
 
 
 def flash_attention(q, k, v, q_pos, k_pos, causal=True, window=0,
-                    k_valid=None, block_q=512, block_k=512):
-    """Fused attention with a fused blockwise backward (see _fa module)."""
+                    k_valid=None, block_q=512, block_k=512, prefix=0):
+    """Fused attention with a fused blockwise backward (see _fa module).
+    With a window, keys at positions below `prefix` stay visible."""
     return _flash_attention(q, k, v, q_pos, k_pos, k_valid, bool(causal),
-                            int(window), int(block_q), int(block_k))
+                            int(window), int(prefix) if window > 0 else 0,
+                            int(block_q), int(block_k))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
 def _flash_attention(q, k, v, q_pos, k_pos, k_valid, causal, window,
-                     block_q, block_k):
+                     prefix, block_q, block_k):
     return _fa.flash_attention_fwd(q, k, v, q_pos, k_pos, causal=causal,
                                    window=window, k_valid=k_valid,
-                                   block_q=block_q, block_k=block_k,
-                                   interpret=INTERPRET)
+                                   prefix=prefix, block_q=block_q,
+                                   block_k=block_k, interpret=INTERPRET)
 
 
-def _fa_fwd(q, k, v, q_pos, k_pos, k_valid, causal, window, block_q,
-            block_k):
+def _fa_fwd(q, k, v, q_pos, k_pos, k_valid, causal, window, prefix,
+            block_q, block_k):
     out, lse = _fa.flash_attention_fwd(q, k, v, q_pos, k_pos, causal=causal,
                                        window=window, k_valid=k_valid,
-                                       block_q=block_q, block_k=block_k,
-                                       return_lse=True, interpret=INTERPRET)
+                                       prefix=prefix, block_q=block_q,
+                                       block_k=block_k, return_lse=True,
+                                       interpret=INTERPRET)
     # residuals carry the mask: fwd/bwd agree by construction
     return out, (q, k, v, q_pos, k_pos, k_valid, out, lse)
 
 
-def _fa_bwd(causal, window, block_q, block_k, res, g):
+def _fa_bwd(causal, window, prefix, block_q, block_k, res, g):
     q, k, v, q_pos, k_pos, k_valid, out, lse = res
     dq, dk, dv = _fa.flash_attention_bwd(
         q, k, v, q_pos, k_pos, k_valid, out, lse, g, causal=causal,
-        window=window, block_q=block_q, block_k=block_k,
+        window=window, prefix=prefix, block_q=block_q, block_k=block_k,
         interpret=INTERPRET)
     return dq, dk, dv, None, None, None
 

@@ -6,12 +6,18 @@ Three interchangeable implementations of the core softmax(QK^T)V:
   * naive      — materializes scores; the oracle, decode, and short
                  sequences where the flash kernel does not run.
   * blockwise  — online-softmax scan over KV blocks, pure jnp. This is the
-                 memory-efficient default for long sequences and mirrors
-                 the structure of the Pallas flash kernel.
+                 memory-efficient path for long sequences off a single
+                 TPU device and mirrors the structure of the Pallas flash
+                 kernel.
   * pallas     — the TPU flash kernel (repro.kernels); CPU-validated in
                  interpret mode. "auto" runs it on a single TPU device
-                 for multi-token calls over at most 2048 keys with no
-                 cache (`resolve_impl`).
+                 for every multi-token call with no cache, at any key
+                 count and with a visible prefix, and for a causal call
+                 over precomputed K/V (the second layer of a K/V-sharing
+                 pair) (`resolve_impl`). Its grid visits only the tiles
+                 the causal / window / prefix mask leaves live, so a
+                 windowed layer's work grows with its window, not with
+                 the sequence.
 """
 from __future__ import annotations
 
@@ -201,25 +207,28 @@ def _cache_insert(cache, k_new, v_new, positions):
 
 
 def resolve_impl(impl, backend, sq, sk, *, has_cache, has_precomputed_kv,
-                 devices=1, prefix=0):
+                 devices=1, causal=False):
     """The core implementation a call runs, from what it can observe.
 
     An explicit impl is kept, except that single-token decode never scans
     KV blocks: its scores are [B, H, 1, Sk], cheap to materialize, and a
-    seq-sharded cache is not resharded into blocks. "auto" picks
-    blockwise above 2048 keys, and the Pallas flash kernel for a
-    multi-token call with no cache and no visible prefix on a single TPU
-    device (a Mosaic kernel cannot be partitioned over several, and the
-    kernel's mask has no prefix); naive otherwise."""
+    seq-sharded cache is not resharded into blocks. "auto" picks the
+    Pallas flash kernel on a single TPU device (a Mosaic kernel cannot be
+    partitioned over several) for a multi-token call with no cache, at
+    any key count, with or without a visible prefix; precomputed K/V
+    qualify when the call is causal (the sequence's own K/V, as the
+    second layer of a K/V-sharing pair passes them), not when it is
+    cross-attention over encoder K/V. Off that path, blockwise above 2048
+    keys and naive otherwise."""
     if impl == "blockwise" and sq == 1:
         return "naive"
     if impl != "auto":
         return impl
+    if (backend == "tpu" and devices == 1 and sq > 1 and not has_cache
+            and (causal or not has_precomputed_kv)):
+        return "pallas"
     if sq > 1 and sk > 2048:
         return "blockwise"
-    if (backend == "tpu" and devices == 1 and sq > 1 and not has_cache
-            and not has_precomputed_kv and not prefix):
-        return "pallas"
     return "naive"
 
 
@@ -316,9 +325,15 @@ def apply_attention(params, x, cfg, *, positions, causal=True, window=0,
     impl = resolve_impl(impl, jax.default_backend(), q.shape[1], sk,
                         has_cache=cache is not None,
                         has_precomputed_kv=precomputed_kv is not None,
-                        devices=jax.device_count(), prefix=prefix)
+                        devices=jax.device_count(), causal=causal)
+    tiles = {}
+    if impl == "pallas":
+        from repro.kernels import flash_attention as _fa
+        tiles = dict(zip(("tiles", "live_tiles"), _fa.tile_counts(
+            q.shape[1], sk, h, kh, hd, q.dtype.itemsize, causal=causal,
+            window=window, prefix=prefix)))
     _rec.get().event("attn/impl", impl=impl, sq=q.shape[1], sk=sk,
-                     causal=causal, prefix=prefix)
+                     causal=causal, prefix=prefix, **tiles)
 
     if impl == "naive":
         bias = _mask_bias(flat_pos, k_pos, causal, window, k_valid, prefix)
@@ -328,12 +343,10 @@ def apply_attention(params, x, cfg, *, positions, causal=True, window=0,
                                    causal, window, k_valid, block=block,
                                    prefix=prefix)
     elif impl == "pallas":
-        if prefix and window:
-            raise ValueError("the flash kernel has no visible prefix")
         from repro.kernels import ops as kops
         out = kops.flash_attention(q, k_all, v_all, flat_pos, k_pos,
                                    causal=causal, window=window,
-                                   k_valid=k_valid)
+                                   k_valid=k_valid, prefix=prefix)
     else:
         raise ValueError(f"unknown attention impl {impl!r}")
 

@@ -37,14 +37,16 @@ def _qkv(key, b, sq, sk, h, kh, hd, dtype=jnp.float32):
 # flash attention backward
 
 
-def _case(*args, dtype=jnp.float32, slow=True, id=None):
-    """A case of the backward sweep, named as before the dtype joined."""
-    return pytest.param(*args, dtype, id=id or "-".join(map(str, args)),
+def _case(*args, prefix=0, dtype=jnp.float32, slow=True, id=None):
+    """A case of the backward sweep, named as before the dtype and the
+    prefix joined."""
+    return pytest.param(*args, prefix, dtype,
+                        id=id or "-".join(map(str, args)),
                         marks=[pytest.mark.slow] if slow else [])
 
 
 @pytest.mark.parametrize(
-    "b,sq,sk,h,kh,hd,bq,bk,causal,window,dtype",
+    "b,sq,sk,h,kh,hd,bq,bk,causal,window,prefix,dtype",
     [
         _case(1, 128, 128, 4, 4, 32, 64, 64, True, 0,
               slow=False),                 # MHA causal
@@ -58,20 +60,27 @@ def _case(*args, dtype=jnp.float32, slow=True, id=None):
         # that is no multiple of 8, every head in one grid step
         _case(2, 34, 34, 12, 12, 64, 512, 512, False, 0, dtype=jnp.bfloat16,
               slow=False, id="vit-2-34-34-12-12-64-nomask-bf16"),
+        # hymba's classes at a small size: GQA, 7 x 7 blocks over a
+        # sequence that is no block multiple, so whole tiles are skipped
+        # in all three kernels; windowed with a visible prefix, and causal
+        _case(2, 400, 400, 4, 2, 32, 64, 64, True, 96, prefix=16,
+              slow=False, id="2-400-400-4-2-32-64-64-True-96-prefix16"),
+        _case(2, 400, 400, 4, 2, 32, 64, 64, True, 0, slow=False),
     ])
 def test_flash_backward_matches_ref_vjp(b, sq, sk, h, kh, hd, bq, bk,
-                                        causal, window, dtype):
+                                        causal, window, prefix, dtype):
     key = jax.random.PRNGKey(42)
     q, k, v, qp, kp = _qkv(key, b, sq, sk, h, kh, hd, dtype)
     tol = ATOL if dtype == jnp.float32 else BF16_TOL
 
     def f_ker(q, k, v):
         return ops.flash_attention(q, k, v, qp, kp, causal=causal,
-                                   window=window, block_q=bq, block_k=bk)
+                                   window=window, block_q=bq, block_k=bk,
+                                   prefix=prefix)
 
     def f_ref(q, k, v):
         return flash_attention_ref(q, k, v, qp, kp, causal=causal,
-                                   window=window)
+                                   window=window, prefix=prefix)
 
     out_k, vjp_k = jax.vjp(f_ker, q, k, v)
     out_r, vjp_r = jax.vjp(f_ref, q, k, v)

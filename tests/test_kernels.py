@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import flash_attention as fa
 from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.quant8 import quant_dequant_fwd
@@ -26,37 +27,110 @@ SS_TOL = {jnp.float32: 1e-5, jnp.bfloat16: 2e-2}
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize(
-    "b,sq,sk,h,kh,hd,bq,bk,causal",
+    "b,sq,sk,h,kh,hd,bq,bk,causal,window,prefix",
     [
-        pytest.param(1, 128, 128, 4, 4, 32, 64, 64, True,
+        pytest.param(1, 128, 128, 4, 4, 32, 64, 64, True, 0, 0,
                      id="1-128-128-4-4-32-64-64"),     # MHA square
-        pytest.param(2, 128, 256, 8, 2, 64, 64, 128, True,
+        pytest.param(2, 128, 256, 8, 2, 64, 64, 128, True, 0, 0,
                      marks=pytest.mark.slow,
                      id="2-128-256-8-2-64-64-128"),    # GQA, rectangular
-        pytest.param(1, 256, 128, 6, 3, 16, 128, 64, True,
+        pytest.param(1, 256, 128, 6, 3, 16, 128, 64, True, 0, 0,
                      marks=pytest.mark.slow,
                      id="1-256-128-6-3-16-128-64"),    # odd head count
-        pytest.param(2, 64, 64, 2, 1, 128, 64, 64, True,
+        pytest.param(2, 64, 64, 2, 1, 128, 64, 64, True, 0, 0,
                      marks=pytest.mark.slow,
                      id="2-64-64-2-1-128-64-64"),      # MQA, wide head
         # the ViT's class: no mask, one block spanning a sequence that is
         # no multiple of 8, every head in one grid step
-        pytest.param(2, 34, 34, 12, 12, 64, 512, 512, False,
+        pytest.param(2, 34, 34, 12, 12, 64, 512, 512, False, 0, 0,
                      id="vit-2-34-34-12-12-64-nomask"),
+        # hymba's classes at a small size: GQA, 7 x 7 blocks over a
+        # sequence that is no block multiple, so whole tiles are skipped;
+        # windowed with a visible prefix, and causal alone
+        pytest.param(2, 400, 400, 4, 2, 32, 64, 64, True, 96, 16,
+                     id="2-400-400-4-2-32-64-64-window96-prefix16"),
+        pytest.param(2, 400, 400, 4, 2, 32, 64, 64, True, 0, 0,
+                     id="2-400-400-4-2-32-64-64-causal"),
     ])
-def test_flash_vs_ref_shapes(b, sq, sk, h, kh, hd, bq, bk, causal, dtype):
+def test_flash_vs_ref_shapes(b, sq, sk, h, kh, hd, bq, bk, causal, window,
+                             prefix, dtype):
     key = jax.random.PRNGKey(0)
     q = jax.random.normal(key, (b, sq, h, hd), dtype)
     k = jax.random.normal(jax.random.fold_in(key, 1), (b, sk, kh, hd), dtype)
     v = jax.random.normal(jax.random.fold_in(key, 2), (b, sk, kh, hd), dtype)
     qp = jnp.broadcast_to(jnp.arange(sq)[None], (b, sq)).astype(jnp.int32)
     kp = jnp.broadcast_to(jnp.arange(sk)[None], (b, sk)).astype(jnp.int32)
-    out = flash_attention_fwd(q, k, v, qp, kp, causal=causal,
-                              block_q=bq, block_k=bk, interpret=True)
-    ref = flash_attention_ref(q, k, v, qp, kp, causal=causal)
+    out = flash_attention_fwd(q, k, v, qp, kp, causal=causal, window=window,
+                              prefix=prefix, block_q=bq, block_k=bk,
+                              interpret=True)
+    ref = flash_attention_ref(q, k, v, qp, kp, causal=causal, window=window,
+                              prefix=prefix)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                atol=TOL[dtype], rtol=TOL[dtype])
+
+
+@pytest.mark.parametrize("s,causal,window,prefix,block,layout,want", [
+    (400, True, 96, 16, 64, "contiguous", None),
+    (400, True, 0, 0, 64, "contiguous", None),
+    (400, False, 96, 16, 64, "contiguous", None),
+    (400, False, 0, 0, 64, "contiguous", None),   # masked by padding only
+    (400, True, 96, 16, 64, "shuffled", None),
+    (400, True, 96, 16, 64, "ragged", None),
+    # hymba-1.5b as published: 4224 positions, blocks of 256 (its plan)
+    (4224, True, 1024, 128, 256, "contiguous", (289, 87)),
+    (4224, True, 0, 0, 256, "contiguous", (289, 153)),
+])
+def test_flash_tile_skip_matches_mask(s, causal, window, prefix, block,
+                                      layout, want):
+    """The kernels' skip test keeps every tile in which the materialized
+    mask keeps a pair, and (contiguous positions) no other; the tile count
+    that `attn/impl` reports matches it."""
+    b = 2
+    pos = np.broadcast_to(np.arange(s), (b, s))
+    valid = None
+    if layout == "shuffled":       # any positions: the test stays sound
+        pos = np.stack([np.random.default_rng(i).permutation(s)
+                        for i in range(b)])
+    elif layout == "ragged":       # decode-style: keys past 300 unfilled
+        valid = jnp.asarray(pos < 300)
+    pos = jnp.asarray(pos, jnp.int32)
+    ones = jnp.zeros((b, s, 1, 1))
+    _, _, _, qp, kp, kv = fa._pad_inputs(ones, ones, ones, pos, pos, valid,
+                                         block, block)
+    n = qp.shape[1] // block
+    bounds = np.asarray(fa._tile_bounds(qp, kp, kv, s, block, block,
+                                        causal, window, prefix))
+    (q_lo, q_hi, k_lo, k_hi, k_first, k_last, q_first,
+     q_last) = np.split(bounds.reshape(b, 8 * n), 8, axis=1)
+    live = np.broadcast_to(fa._may_see(
+        q_lo[:, :, None], q_hi[:, :, None], k_lo[:, None], k_hi[:, None],
+        causal, window, prefix), (b, n, n))
+    # the clamped index maps never turn a live step away from its block
+    idx = np.arange(n)
+    assert not (live & ((idx < k_first[:, :, None])
+                        | (idx > k_last[:, :, None]))).any()
+    assert not (live & ((idx[:, None] < q_first[:, None])
+                        | (idx[:, None] > q_last[:, None]))).any()
+    qp, kp = np.asarray(qp)[:, :, None], np.asarray(kp)[:, None, :]
+    ok = np.asarray(kv)[:, None, :] & (np.arange(n * block) < s)[None, :,
+                                                                  None]
+    if causal:
+        ok = ok & (kp <= qp)
+    if window:
+        ok = ok & (((qp - kp) < window) | (kp < prefix))
+    seen = ok.reshape(b, n, block, n, block).any(axis=(2, 4))
+    assert not (seen & ~live).any()          # never skips a visible pair
+    if layout == "contiguous":
+        np.testing.assert_array_equal(live, seen)
+        # hymba's widths leave `_plan` its 256-row blocks; the small
+        # case names its blocks
+        h, kh, hd, itemsize, blocks = (25, 5, 64, 2, {}) if block == 256 \
+            else (4, 2, 32, 4, dict(block_q=block, block_k=block))
+        counts = fa.tile_counts(s, s, h, kh, hd, itemsize, causal=causal,
+                                window=window, prefix=prefix, **blocks)
+        assert counts == (n * n, int(seen[0].sum()))
+        assert want in (None, counts)
 
 
 @pytest.mark.parametrize("causal,window", [(True, 0), (False, 0), (True, 32)])

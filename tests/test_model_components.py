@@ -47,24 +47,70 @@ def test_pallas_impl_matches_naive():
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=2e-4)
 
 
-@pytest.mark.parametrize("impl,backend,sq,sk,cache,pre,devices,want", [
-    ("auto", "tpu", 274, 274, False, False, 1, "pallas"),  # the ViT trunk
-    ("auto", "cpu", 274, 274, False, False, 1, "naive"),
-    ("auto", "tpu", 4096, 4096, False, False, 1, "blockwise"),
-    ("auto", "tpu", 1, 4096, False, False, 1, "naive"),    # decode
-    ("auto", "tpu", 1, 4096, True, False, 1, "naive"),     # cached decode
-    ("auto", "tpu", 274, 274, True, False, 1, "naive"),    # cached prefill
-    ("auto", "tpu", 274, 1500, False, True, 1, "naive"),   # cross-attn KV
-    ("auto", "tpu", 274, 274, False, False, 4, "naive"),   # partitioned
-    ("naive", "tpu", 274, 274, False, False, 1, "naive"),
-    ("pallas", "cpu", 274, 274, True, False, 1, "pallas"),
-    ("blockwise", "tpu", 274, 274, False, False, 1, "blockwise"),
-    ("blockwise", "tpu", 1, 4096, True, False, 1, "naive"),
-])
-def test_resolve_impl(impl, backend, sq, sk, cache, pre, devices, want):
+def _route(*row, causal=True):
+    """A resolve_impl case, named by its row as before `causal` joined."""
+    return pytest.param(*row, causal, id="-".join(map(str, row)))
+
+
+@pytest.mark.parametrize(
+    "impl,backend,sq,sk,cache,pre,devices,want,causal", [
+        _route("auto", "tpu", 274, 274, False, False, 1, "pallas"),  # ViT
+        _route("auto", "cpu", 274, 274, False, False, 1, "naive"),
+        _route("auto", "tpu", 4096, 4096, False, False, 1, "pallas"),
+        _route("auto", "tpu", 4224, 4224, False, False, 1,
+               "pallas"),                      # hymba with its meta keys
+        _route("auto", "tpu", 4224, 4224, False, True, 1,
+               "pallas"),                      # second layer of a K/V pair
+        _route("auto", "cpu", 4096, 4096, False, False, 1, "blockwise"),
+        _route("auto", "tpu", 4096, 4096, False, False, 4, "blockwise"),
+        _route("auto", "tpu", 1, 4096, False, False, 1, "naive"),  # decode
+        _route("auto", "tpu", 1, 4096, True, False, 1,
+               "naive"),                       # cached decode
+        _route("auto", "tpu", 274, 274, True, False, 1,
+               "naive"),                       # cached prefill
+        _route("auto", "tpu", 274, 1500, False, True, 1, "naive",
+               causal=False),                  # cross-attn encoder KV
+        _route("auto", "tpu", 274, 274, False, False, 4,
+               "naive"),                       # partitioned
+        _route("naive", "tpu", 274, 274, False, False, 1, "naive"),
+        _route("pallas", "cpu", 274, 274, True, False, 1, "pallas"),
+        _route("blockwise", "tpu", 274, 274, False, False, 1, "blockwise"),
+        _route("blockwise", "tpu", 1, 4096, True, False, 1, "naive"),
+    ])
+def test_resolve_impl(impl, backend, sq, sk, cache, pre, devices, want,
+                      causal):
     assert attention.resolve_impl(impl, backend, sq, sk, has_cache=cache,
-                                  has_precomputed_kv=pre,
-                                  devices=devices) == want
+                                  has_precomputed_kv=pre, devices=devices,
+                                  causal=causal) == want
+
+
+def test_pallas_attention_event_counts_live_tiles(tmp_path):
+    """A call that resolves to the flash kernel reports its grid tiles
+    and the live ones it visits (at trace time; nothing runs): a windowed
+    call with a visible prefix over 1100 positions, in 3 x 3 blocks."""
+    import json
+    from repro import obs
+    from repro.kernels import flash_attention as fa
+    cfg = _attn_cfg()
+    key = jax.random.PRNGKey(2)
+    p = attention.init_attention(key, cfg)
+    s = 1100
+    x = jax.ShapeDtypeStruct((1, s, cfg.d_model), jnp.float32)
+    pos = layers.positions_from_shape(1, s)
+    log = tmp_path / "run.jsonl"
+    with obs.enabled(str(log)):
+        jax.jit(lambda p, x: attention.apply_attention(
+            p, x, cfg, positions=pos, causal=True, window=256, prefix=16,
+            impl="pallas")[0]).lower(p, x)
+    (event,) = [r["fields"] for r in map(json.loads,
+                                         log.read_text().splitlines())
+                if r.get("name") == "attn/impl"]
+    tiles, live = fa.tile_counts(s, s, cfg.num_heads, cfg.num_kv_heads,
+                                 cfg.resolved_head_dim, 4, causal=True,
+                                 window=256, prefix=16)
+    assert event == {"impl": "pallas", "sq": s, "sk": s, "causal": True,
+                     "prefix": 16, "tiles": tiles, "live_tiles": live}
+    assert (tiles, live) == (9, 6)
 
 
 def test_auto_attention_on_cpu_is_naive_and_recorded(tmp_path):

@@ -2,8 +2,9 @@
 
 Interpret mode (every other kernel test) cannot see the TPU's tiling and
 VMEM rules, so each kernel is compiled here, forward and backward, for a
-described (not attached) v5e chip: head_dim 128 and 64 at 4096 tokens
-and the ViT-B/16 trunk's 274, vocab 256000 and 32001, d_inner 8192 and
+described (not attached) v5e chip: head_dim 128 and 64 at 4096 tokens,
+hymba-1.5b's 4224 positions with its visible meta prefix, and the
+ViT-B/16 trunk's 274, vocab 256000 and 32001, d_inner 8192 and
 hymba-1.5b's 3200 at 4224 positions.
 Nothing runs. The topology is described inside a
 fixture, never at import, and the persistent compilation cache is off
@@ -55,33 +56,38 @@ def _compile(fn, one_chip, *shapes):
 
 BF, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
-# (heads, kv heads, head_dim, window, batch, seq, causal): minitron-4b
-# global layers and hymba-1.5b's windowed layers at 4096 tokens with a
-# key-validity mask; the ViT-B/16 trunk of the meta-transformer-b16 cell
-# (8 clients x 32 samples of 274 tokens), with no mask at all
-FLASH = {"hd128": (32, 8, 128, 0, 1, 4096, True),
-         "hd64": (25, 5, 64, 1024, 1, 4096, True),
-         "vit_b16": (12, 12, 64, 0, 256, 274, False)}
+# (heads, kv heads, head_dim, window, batch, seq, causal, prefix):
+# minitron-4b global layers and hymba-1.5b's windowed layers at 4096
+# tokens with a key-validity mask; the ViT-B/16 trunk of the
+# meta-transformer-b16 cell (8 clients x 32 samples of 274 tokens), with
+# no mask at all; hymba-1.5b as published (4 clients, 4096 tokens and 128
+# meta tokens, which its windowed layers keep in view), as the model
+# passes it: no key-validity mask, so only the grid's padding masks keys
+FLASH = {"hd128": (32, 8, 128, 0, 1, 4096, True, 0),
+         "hd64": (25, 5, 64, 1024, 1, 4096, True, 0),
+         "vit_b16": (12, 12, 64, 0, 256, 274, False, 0),
+         "hymba_published": (25, 5, 64, 1024, 4, 4224, True, 128)}
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 @pytest.mark.parametrize("case", sorted(FLASH))
 def test_flash_attention_compiles_for_v5e(one_chip, case, direction):
-    h, kh, hd, window, b, s, causal = FLASH[case]
+    h, kh, hd, window, b, s, causal, prefix = FLASH[case]
     q, kv = ((b, s, h, hd), BF), ((b, s, kh, hd), BF)
     pos = ((b, s), I32)
-    masks = [((b, s), jnp.bool_)] if causal else []
+    masks = [((b, s), jnp.bool_)] if causal and not prefix else []
 
     def valid(m):
         return m[0] if m else None
     if direction == "fwd":
         _compile(lambda q, k, v, p, *m: fa.flash_attention_fwd(
             q, k, v, p, p, causal=causal, window=window, k_valid=valid(m),
-            return_lse=True), one_chip, q, kv, kv, pos, *masks)
+            prefix=prefix, return_lse=True), one_chip, q, kv, kv, pos,
+            *masks)
     else:
         _compile(lambda q, k, v, p, o, lse, do, *m: fa.flash_attention_bwd(
             q, k, v, p, p, valid(m), o, lse, do, causal=causal,
-            window=window),
+            window=window, prefix=prefix),
             one_chip, q, kv, kv, pos, q, ((b, h, s), F32), q, *masks)
 
 
