@@ -1,7 +1,6 @@
 """Serve a (post-training-assembled) model with batched requests: one
 prefill + greedy decode loop with a KV cache — the inference side of the
-framework that the decode_32k / long_500k dry-run cells exercise at
-production scale.
+framework.
 
     PYTHONPATH=src python examples/serve_batched.py --arch minitron-4b
     PYTHONPATH=src python examples/serve_batched.py --arch falcon-mamba-7b

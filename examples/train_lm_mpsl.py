@@ -5,9 +5,9 @@ after a simulated failure.
     PYTHONPATH=src python examples/train_lm_mpsl.py [--arch minitron-4b]
     PYTHONPATH=src python examples/train_lm_mpsl.py --arch qwen2-moe-a2.7b
 
-Reduced same-family configs run on CPU; the full-size production run is
-``python -m repro.launch.train --full`` on the real mesh (see also the
-multi-pod dry-run for its sharding proof).
+Reduced same-family configs run on CPU; the full-size run is
+``python -m repro.launch.train --full`` over every device the process
+sees.
 """
 import argparse
 import os

@@ -12,7 +12,6 @@ from repro.configs.base import (
     ShapeConfig,
     SHAPES,
     SSMConfig,
-    cell_supported,
     reduced,
 )
 
@@ -67,5 +66,5 @@ def list_archs():
 __all__ = [
     "ARCHS", "ASSIGNED_ARCHS", "PAPER_ARCHS", "PUBLISHED_MECHANISMS", "SHAPES",
     "ModelConfig", "MoEConfig", "MPSLConfig", "RunConfig", "ShapeConfig",
-    "SSMConfig", "cell_supported", "get_config", "list_archs", "reduced",
+    "SSMConfig", "get_config", "list_archs", "reduced",
 ]

@@ -5,7 +5,8 @@ Three layers of config:
   * ShapeConfig  — input-shape cell (seq_len x global_batch x kind).
   * MPSLConfig   — the paper's technique: split point, client population,
                    fusion, compression, fine-tuned-block count.
-  * RunConfig    — bundles the three + mesh/runtime knobs.
+  * RunConfig    — bundles the three with the numerics and optimizer
+                   settings.
 """
 from __future__ import annotations
 
@@ -55,8 +56,6 @@ class ModelConfig:
     tie_embeddings: bool = False
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
-    # hybrid (Hymba): parallel attention + SSM heads inside each block
-    hybrid: bool = False
     # sliding-window size for local-attention layers (0 = all global)
     sliding_window: int = 0
     # indices of global-attention layers when sliding_window > 0
@@ -91,15 +90,6 @@ class ModelConfig:
             return 0
         return self.ssm.dt_rank or -(-self.d_model // 16)
 
-    @property
-    def attention_free(self) -> bool:
-        return self.family == "ssm"
-
-    @property
-    def subquadratic(self) -> bool:
-        """Supports O(1)-state or bounded-window decode at 500k context."""
-        return self.family in ("ssm", "hybrid")
-
     def param_count(self, trainable_blocks: Optional[int] = None) -> int:
         """Analytic parameter count (embeddings + blocks + head)."""
         from repro.models.model import count_params_analytic
@@ -117,10 +107,6 @@ class ShapeConfig:
     global_batch: int
     kind: str                       # train | prefill | decode
 
-    @property
-    def is_training(self) -> bool:
-        return self.kind == "train"
-
 
 SHAPES = {
     "train_4k": ShapeConfig("train_4k", 4_096, 256, "train"),
@@ -128,13 +114,6 @@ SHAPES = {
     "decode_32k": ShapeConfig("decode_32k", 32_768, 128, "decode"),
     "long_500k": ShapeConfig("long_500k", 524_288, 1, "decode"),
 }
-
-
-def cell_supported(model: ModelConfig, shape: ShapeConfig) -> Tuple[bool, str]:
-    """Whether an (arch x shape) dry-run cell runs, and why not if skipped."""
-    if shape.name == "long_500k" and not model.subquadratic:
-        return False, "SKIP(full-attention: 524k context needs sub-quadratic attention)"
-    return True, ""
 
 
 # ---------------------------------------------------------------------------
@@ -170,8 +149,6 @@ class RunConfig:
     model: ModelConfig
     shape: ShapeConfig
     mpsl: MPSLConfig = dataclasses.field(default_factory=MPSLConfig)
-    # mesh
-    multi_pod: bool = False
     # numerics
     compute_dtype: str = "bfloat16"
     param_dtype: str = "float32"        # trainable params / master copies
@@ -180,50 +157,7 @@ class RunConfig:
     learning_rate: float = 1e-4
     weight_decay: float = 0.01
     grad_clip: float = 1.0
-    microbatches: int = 1               # grad accumulation
-    remat: str = "block"                # none | block | full
     seed: int = 0
-    # implementation selection (perf knobs)
-    attn_impl: str = "auto"             # auto | naive | blockwise | pallas
-    attn_block: int = 1024              # blockwise attention KV block
-    moe_impl: str = "dense"             # dense | ragged | ep
-    moe_capacity: float = 2.0           # EP per-expert capacity slack
-    ssm_impl: str = "auto"              # auto | jnp | pallas
-    ssm_chunk: int = 256                # selective-scan chunk length (most)
-    # selective-scan backward lowering (pallas path only): 'fused' runs the
-    # checkpointed-recompute adjoint kernel; 'recompute' falls back to
-    # jax.vjp through the jnp reference (the pre-fusion oracle path)
-    ssm_bwd_impl: str = "fused"
-    ce_impl: str = "jnp"                # jnp | pallas (fused LM-head CE)
-    ce_chunk: int = 512                 # chunked-CE token block
-    # sequence-parallel residual activations (Korthikanti-style SP): the
-    # per-layer scan carry is sharded on seq over the TP axis, cutting the
-    # remat stash by the TP width; matmul regions re-gather.
-    seq_shard_acts: bool = False
-    # fully unroll layer scans (roofline probes only — makes HLO cost
-    # analysis see every layer)
-    unroll_layers: bool = False
-    # sequence-parallel attention math (beyond-paper): shard the query seq
-    # over the TP axis when the head count doesn't divide it
-    attn_seq_shard: bool = False
-    # serving: keep weights FSDP-sharded over data (True) or replicate
-    # over data, TP-only (False — kills the per-token weight all-gathers
-    # when the TP-sharded weights fit HBM)
-    serve_weights_fsdp: bool = True
-
-    @property
-    def impls(self):
-        return {"attn": self.attn_impl, "attn_block": self.attn_block,
-                "moe": self.moe_impl, "moe_capacity": self.moe_capacity,
-                "ssm": self.ssm_impl,
-                "ssm_chunk": self.ssm_chunk,
-                "ssm_bwd": self.ssm_bwd_impl,
-                "ce": self.ce_impl,
-                "unroll_layers": self.unroll_layers,
-                "attn_seq_shard": self.attn_seq_shard,
-                "act_dims": (("batch", "seq_model", None)
-                             if self.seq_shard_acts
-                             else ("batch", None, None))}
 
 
 def reduced(model: ModelConfig, **overrides) -> ModelConfig:

@@ -28,7 +28,6 @@ CONFIG = ModelConfig(
     head_dim=64,
     activation="silu",
     norm="rmsnorm",
-    hybrid=True,
     ssm=SSMConfig(d_state=16, d_conv=4, expand=2),
     sliding_window=1024,
     global_layers=(0, 15, 31),

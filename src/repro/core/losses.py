@@ -15,10 +15,11 @@ def chunked_softmax_xent(h, w, labels, valid=None, chunk: int = 512,
 
     h [T, D], w [D, V], labels [T] -> per-token loss [T].
 
-    impl='jnp' (the oracle): `chunk`-token slices under jax.checkpoint so
-    the backward recomputes each chunk's logits instead of saving them.
-    impl='pallas': the fused online-softmax kernel (repro.kernels) —
-    vocab-tiled in both directions, selected via `run.impls['ce']`.
+    impl='jnp' (what the train step runs, and the oracle): `chunk`-token
+    slices under jax.checkpoint so the backward recomputes each chunk's
+    logits instead of saving them.
+    impl='pallas': the fused online-softmax kernel (repro.kernels),
+    vocab-tiled in both directions; only tests select it.
     """
     if impl == "pallas":
         from repro.kernels import ops as kops

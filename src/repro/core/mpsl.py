@@ -71,22 +71,19 @@ def _account_links(h, mpsl, suffix: str = ""):
                          wire_bytes_per_client=wire)
 
 
-def _run_body(frozen, server, cfg, h, positions, impls, remat,
-              enc_out=None):
+def _run_body(frozen, server, cfg, h, positions, enc_out=None):
     """Frozen prefix + trainable suffix, then final norm."""
     aux = jnp.zeros((), jnp.float32)
     fsegs, tsegs = _segments_for(frozen, server, cfg)
     with jax.named_scope("frozen_trunk"):
         for sp, seg in zip(frozen["segments"], fsegs):
             h, _, a = M.apply_segment(sp, h, cfg, seg, positions=positions,
-                                      enc_out=enc_out, impls=impls,
-                                      remat=remat)
+                                      enc_out=enc_out)
             aux = aux + a
     with jax.named_scope("trainable_trunk"):
         for sp, seg in zip(server["segments"], tsegs):
             h, _, a = M.apply_segment(sp, h, cfg, seg, positions=positions,
-                                      enc_out=enc_out, impls=impls,
-                                      remat=remat)
+                                      enc_out=enc_out)
             aux = aux + a
     h = layers.apply_norm(h, server["final_norm"], cfg.norm)
     return h, aux
@@ -113,8 +110,6 @@ def make_lm_loss(cfg, run):
             frame_embeds [N, Bn, F, D] for audio)."""
     mpsl = run.mpsl
     cdt = jnp.dtype(run.compute_dtype)
-    impls = dict(run_impls(run))
-    remat = run.remat != "none"
     owners = M.kv_share_pairs(cfg)
 
     def loss_fn(trainable, frozen, batch, rng):
@@ -164,11 +159,11 @@ def make_lm_loss(cfg, run):
             fe = batch["frame_embeds"].astype(cdt)
             fe = split.apply_client_adapter(trainable["client"]["adapter"], fe)
             fe = fe.reshape(n * bn, fe.shape[2], cfg.d_model)
-            enc_out = M.run_encoder(frozen, fe, cfg, impls=impls, remat=remat)
+            enc_out = M.run_encoder(frozen, fe, cfg)
 
         # ---- 3. server forward: ONE pass over the global batch ----
         hb, aux = _run_body(frozen, trainable["server"], cfg, hb, positions,
-                            impls, remat, enc_out=enc_out)
+                            enc_out=enc_out)
 
         # ---- 4. tail in CLIENT layout: labels never leave their shard ----
         hc = hb.reshape(n, bn, seq, cfg.d_model)
@@ -182,9 +177,7 @@ def make_lm_loss(cfg, run):
         w_tail = (trainable["server"]["lm_head"] if "lm_head"
                   in trainable["server"] else
                   frozen["embed"]["table"].T)
-        per_tok = losses.chunked_softmax_xent(
-            flat_h, w_tail, flat_l, chunk=run_ce_chunk(run),
-            impl=impls.get("ce", "jnp"))
+        per_tok = losses.chunked_softmax_xent(flat_h, w_tail, flat_l)
         per_client = per_tok.reshape(n, -1).mean(axis=1)        # L_n
 
         # ---- 5. aggregated loss => single backward pass ----
@@ -211,14 +204,6 @@ def _build_positions(cfg, batch, b, seq):
     return layers.positions_from_shape(b, seq)
 
 
-def run_impls(run):
-    return run.impls
-
-
-def run_ce_chunk(run):
-    return run.ce_chunk
-
-
 # ---------------------------------------------------------------------------
 # Paper-mode (ViT / Meta-Transformer) MPSL loss
 
@@ -227,15 +212,11 @@ def make_vit_loss(cfg, run, modalities=("vision", "text"),
                   task: str = "classification", n_classes: int = 10):
     mpsl = run.mpsl
     cdt = jnp.dtype(run.compute_dtype)
-    impls = dict(run_impls(run))
-    remat = run.remat != "none"
 
     def encode(frozen, server, tokens_bnd):
         b = tokens_bnd.shape[0]
         positions = layers.positions_from_shape(b, tokens_bnd.shape[1])
-        h, aux = _run_body(frozen, server, cfg, tokens_bnd, positions,
-                           impls, remat)
-        return h, aux
+        return _run_body(frozen, server, cfg, tokens_bnd, positions)
 
     def loss_fn(trainable, frozen, batch, rng):
         mask = batch["mask"]
@@ -303,7 +284,6 @@ def make_vit_loss(cfg, run, modalities=("vision", "text"),
 
         w = _client_weights(mask, n)
         l_s = jnp.sum(w * per_client) + aux
-        acc = None
         metrics = {"loss": l_s, "per_client": per_client, "aux": aux,
                    "participating": jnp.sum(mask)}
         return l_s, metrics

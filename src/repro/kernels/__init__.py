@@ -7,11 +7,13 @@ Kernel coverage (fused forward / fused backward):
   flash_attention — fwd + bwd. HBM->VMEM blocked online-softmax attention
                     (the body's dominant matmul pair at 4k-32k sequence
                     lengths; attention's "auto" runs it on one TPU device
-                    for multi-token calls over at most 2048 keys). The
-                    forward emits a per-row LSE residual; the backward's
-                    dq and dk/dv kernels recompute probabilities
-                    blockwise from (q, k, v, lse), so no [Sq, Sk]
-                    intermediate exists in either direction.
+                    for every multi-token, cache-free call, at any key
+                    count and with a visible prefix, visiting only the
+                    tiles the mask leaves live). The forward emits a
+                    per-row LSE residual; the backward's dq and dk/dv
+                    kernels recompute probabilities blockwise from
+                    (q, k, v, lse), so no [Sq, Sk] intermediate exists
+                    in either direction.
                     Non-block-multiple sequence lengths are padded onto
                     the block grid with masked keys / zero-cotangent
                     query rows.
@@ -34,8 +36,8 @@ Kernel coverage (fused forward / fused backward):
                     reverse, recomputes the in-chunk states from each
                     checkpoint into VMEM scratch and runs the adjoint
                     recurrence, so no [B, S, di, ds] state history exists
-                    in either direction (run.impls["ssm_bwd"] falls back
-                    to the recompute-through-reference VJP).
+                    in either direction (the recompute-through-reference
+                    VJP, ``bwd="recompute"``, is kept as the tests' oracle).
 
 Interpret-mode caveats: grids execute sequentially in Python (orders of
 magnitude slower than compiled — benchmark numbers from CPU measure

@@ -120,11 +120,11 @@ def selective_scan(x, dt, b_in, c_in, a_log, h0=None, chunk=256,
     """Fused chunked scan; a nonzero h0 seeds the kernel's VMEM state
     directly (no jnp [B,S,di,ds] propagation term).
 
-    ``bwd`` selects the backward lowering (run.impls["ssm_bwd"]):
-    "fused" sweeps chunks in reverse through the Pallas adjoint kernel,
+    ``bwd`` selects the backward lowering: "fused" (what the model runs)
+    sweeps chunks in reverse through the Pallas adjoint kernel,
     recomputing in-chunk states from the forward's boundary checkpoints;
-    "recompute" is the legacy jax.vjp through the jnp reference (kept as
-    the oracle / fallback)."""
+    "recompute" is jax.vjp through the jnp reference, kept as the oracle
+    the tests compare against."""
     return _ss.selective_scan_fwd(x, dt, b_in, c_in, a_log, h0,
                                   chunk=chunk, block_d=block_d,
                                   interpret=INTERPRET)

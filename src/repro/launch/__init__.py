@@ -1,1 +1,1 @@
-"""Launchers: production mesh, multi-pod dry-run, train/serve drivers."""
+"""Launchers: the host mesh, the compile cache and the train/serve drivers."""

@@ -101,7 +101,7 @@ def parse_args(argv=None):
 
 def make_run_config(args, **overrides):
     """(model config, RunConfig) for parsed args; ``overrides`` replace
-    RunConfig fields (e.g. ``attn_impl``)."""
+    RunConfig fields (e.g. ``compute_dtype``)."""
     cfg = get_config(args.arch)
     if args.published_mechanisms:
         cfg = dataclasses.replace(cfg, **PUBLISHED_MECHANISMS[args.arch])

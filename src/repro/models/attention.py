@@ -235,8 +235,7 @@ def resolve_impl(impl, backend, sq, sk, *, has_cache, has_precomputed_kv,
 def apply_attention(params, x, cfg, *, positions, causal=True, window=0,
                     cache=None, impl="auto", cos_sin=None, block=1024,
                     kv_x=None, kv_positions=None, precomputed_kv=None,
-                    use_rope=None, seq_shard=False, prefix=0,
-                    return_kv=False):
+                    use_rope=None, prefix=0, return_kv=False):
     """x [B, S, D] -> (out [B, S, D], new_cache), and with `return_kv` the
     K/V attended, as {'k', 'v', 'pos'} (after RoPE).
 
@@ -246,10 +245,6 @@ def apply_attention(params, x, cfg, *, positions, causal=True, window=0,
     precomputed_kv: {'k','v','pos'} — decode-time cross-attention KV, or
       (Hymba) the K/V that the first layer of a sharing pair computed.
     prefix: with a window, keys at positions below it stay visible.
-    seq_shard: shard the QUERY sequence over the TP axis for the core
-      attention math (beyond-paper optimization for archs whose head count
-      doesn't divide the TP width — without it every TP shard redundantly
-      computes full attention). K/V are gathered once (they are GQA-small).
     """
     h, kh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     q = jnp.einsum("bsd,dhk->bshk", x, params["wq"].astype(x.dtype))
@@ -313,13 +308,6 @@ def apply_attention(params, x, cfg, *, positions, causal=True, window=0,
         k_valid = None
     else:
         k_all, v_all, k_pos, k_valid = k, v, flat_pos, None
-
-    if seq_shard and q.shape[1] > 1:
-        from repro.parallel import sharding as _sh
-        q = _sh.shard_act(q, ("batch", "seq_model", None, None))
-        flat_pos = _sh.shard_act(flat_pos, ("batch", "seq_model"))
-        k_all = _sh.shard_act(k_all, ("batch", None, None, None))
-        v_all = _sh.shard_act(v_all, ("batch", None, None, None))
 
     sk = k_all.shape[1]
     impl = resolve_impl(impl, jax.default_backend(), q.shape[1], sk,

@@ -42,8 +42,7 @@ def init_hybrid(key, cfg, own_kv: bool = True):
 
 
 def apply_hybrid(params, x, cfg, *, positions, is_global, cache=None,
-                 impl="auto", ssm_impl="auto", ssm_bwd="fused",
-                 ssm_chunk=256, seq_shard=False, shared_kv=None):
+                 shared_kv=None):
     """x [B, S, D] -> (y, new_cache, kv). cache = {'kv': ..., 'ssm': ...}.
 
     is_global: static bool — full attention vs sliding window.
@@ -56,13 +55,11 @@ def apply_hybrid(params, x, cfg, *, positions, is_global, cache=None,
     with jax.named_scope("attention"):
         a_out, kv_new, kv = attention.apply_attention(
             params["attn"], x, cfg, positions=positions, causal=True,
-            window=window, cache=kv_cache, impl=impl, seq_shard=seq_shard,
-            precomputed_kv=shared_kv,
+            window=window, cache=kv_cache, precomputed_kv=shared_kv,
             prefix=cfg.meta_tokens if window else 0, return_kv=True)
     with jax.named_scope("ssm"):
-        s_out, ssm_new = mamba.apply_mamba(
-            params["ssm"], x, cfg, cache=ssm_cache, impl=ssm_impl,
-            chunk=ssm_chunk, bwd_impl=ssm_bwd)
+        s_out, ssm_new = mamba.apply_mamba(params["ssm"], x, cfg,
+                                           cache=ssm_cache)
 
     a_out = layers.rms_norm(a_out, params["attn_norm"]["scale"])
     s_out = layers.rms_norm(s_out, params["ssm_norm"]["scale"])

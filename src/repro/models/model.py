@@ -143,7 +143,7 @@ def init_block(key, cfg, kind: BlockKind, own_kv: bool = True):
 
 
 def apply_block(params, x, cfg, kind: BlockKind, *, positions, cache=None,
-                enc_out=None, cross_kv=None, impls=None):
+                enc_out=None, cross_kv=None):
     """One transformer block, or a K/V-sharing pair of hybrid blocks.
     Returns (x, new_cache, aux_loss)."""
     if kind.kv_pair:
@@ -151,52 +151,39 @@ def apply_block(params, x, cfg, kind: BlockKind, *, positions, cache=None,
             raise NotImplementedError("decoding through a K/V-sharing pair")
         one = dataclasses.replace(kind, kv_pair=False)
         x, _, a1, kv = _apply_block(params["first"], x, cfg, one,
-                                    positions=positions, impls=impls)
+                                    positions=positions)
         x, _, a2, _ = _apply_block(params["second"], x, cfg, one,
-                                   positions=positions, impls=impls,
-                                   shared_kv=kv)
+                                   positions=positions, shared_kv=kv)
         return x, None, a1 + a2
     return _apply_block(params, x, cfg, kind, positions=positions,
-                        cache=cache, enc_out=enc_out, cross_kv=cross_kv,
-                        impls=impls)[:3]
+                        cache=cache, enc_out=enc_out,
+                        cross_kv=cross_kv)[:3]
 
 
 def _apply_block(params, x, cfg, kind: BlockKind, *, positions, cache=None,
-                 enc_out=None, cross_kv=None, impls=None, shared_kv=None):
+                 enc_out=None, cross_kv=None, shared_kv=None):
     """One block: (x, new_cache, aux_loss, the K/V a hybrid block attended
     with, or None)."""
-    impls = impls or {}
     aux = jnp.zeros((), jnp.float32)
     h = layers.apply_norm(x, params["norm1"], cfg.norm)
 
     new_cache = kv = None
     if kind.family == "ssm":
         with jax.named_scope("ssm"):
-            out, new_cache = mamba.apply_mamba(
-                params["ssm"], h, cfg, cache=cache,
-                impl=impls.get("ssm", "auto"),
-                chunk=impls.get("ssm_chunk", 256),
-                bwd_impl=impls.get("ssm_bwd", "fused"))
+            out, new_cache = mamba.apply_mamba(params["ssm"], h, cfg,
+                                               cache=cache)
         return x + out, new_cache, aux, None
     if kind.family == "hybrid":
         out, new_cache, kv = hybrid.apply_hybrid(
             params["mix"], h, cfg, positions=positions,
-            is_global=kind.is_global, cache=cache,
-            impl=impls.get("attn", "auto"), ssm_impl=impls.get("ssm", "auto"),
-            ssm_bwd=impls.get("ssm_bwd", "fused"),
-            ssm_chunk=impls.get("ssm_chunk", 256),
-            seq_shard=impls.get("attn_seq_shard", False),
-            shared_kv=shared_kv)
+            is_global=kind.is_global, cache=cache, shared_kv=shared_kv)
         x = x + out
     else:
         window = 0 if kind.is_global else cfg.sliding_window
         with jax.named_scope("attention"):
             out, new_cache = attention.apply_attention(
                 params["attn"], h, cfg, positions=positions,
-                causal=kind.causal, window=window, cache=cache,
-                impl=impls.get("attn", "auto"),
-                block=impls.get("attn_block", 1024),
-                seq_shard=impls.get("attn_seq_shard", False))
+                causal=kind.causal, window=window, cache=cache)
         x = x + out
 
     if kind.cross:
@@ -204,23 +191,20 @@ def _apply_block(params, x, cfg, kind: BlockKind, *, positions, cache=None,
         if cross_kv is not None:
             out, _ = attention.apply_attention(
                 params["cross"], h, cfg, positions=positions, causal=False,
-                precomputed_kv=cross_kv, impl=impls.get("attn", "auto"),
-                use_rope=False)
+                precomputed_kv=cross_kv, use_rope=False)
         else:
             out, _ = attention.apply_attention(
                 params["cross"], h, cfg, positions=positions, causal=False,
-                kv_x=enc_out, impl=impls.get("attn", "auto"), use_rope=False)
+                kv_x=enc_out, use_rope=False)
         x = x + out
 
     h = layers.apply_norm(x, params["norm2"], cfg.norm)
     if "moe" in params:
-        out, aux = moe.apply_moe(params["moe"], h, cfg,
-                                 impl=impls.get("moe", "dense"),
-                                 capacity=impls.get("moe_capacity", 2.0))
+        out, aux = moe.apply_moe(params["moe"], h, cfg)
     else:
         out = mlp.apply_mlp(params["mlp"], h, cfg.activation)
     x = x + out
-    x = sharding.shard_act(x, impls.get("act_dims", ("batch", None, None)))
+    x = sharding.shard_act(x, ("batch", None, None))
     return x, new_cache, aux, kv
 
 
@@ -253,33 +237,26 @@ def init_segment_cache(cfg, seg: Segment, batch: int, cache_len: int,
 
 
 def apply_segment(params, x, cfg, seg: Segment, *, positions, cache=None,
-                  enc_out=None, cross_kv=None, impls=None, remat=True):
+                  enc_out=None, cross_kv=None, remat=True):
     """Scan a stacked segment. Returns (x, new_cache, aux_sum).
 
     Train path (no cache): layer params are scan xs. Serve path: the
     stacked cache is a scan CARRY updated in place with dynamic-update-
     slice on the layer dim — the while loop then aliases the buffer
     instead of allocating a second stacked cache as scan outputs would."""
-
-    # unroll_layers: used by the roofline probes so HLO cost analysis sees
-    # every layer (XLA counts a while-loop body once regardless of trips)
-    unroll = bool((impls or {}).get("unroll_layers", False))
-
     if cache is None:
         def step(carry, xs):
             h, aux = carry
             lp, ckv = xs
             y, _, a = apply_block(lp, h, cfg, seg.kind, positions=positions,
-                                  enc_out=enc_out, cross_kv=ckv,
-                                  impls=impls)
+                                  enc_out=enc_out, cross_kv=ckv)
             return (y, aux + a), None
 
         if remat:
             step = jax.checkpoint(
                 step, policy=jax.checkpoint_policies.nothing_saveable)
         (x, aux), _ = jax.lax.scan(
-            step, (x, jnp.zeros((), jnp.float32)), (params, cross_kv),
-            unroll=seg.count if unroll else 1)
+            step, (x, jnp.zeros((), jnp.float32)), (params, cross_kv))
         return x, None, aux
 
     tmap = jax.tree_util.tree_map
@@ -290,8 +267,7 @@ def apply_segment(params, x, cfg, seg: Segment, *, positions, cache=None,
         lc = tmap(lambda a: jax.lax.dynamic_index_in_dim(
             a, i, 0, keepdims=False), c)
         y, nc, a = apply_block(lp, h, cfg, seg.kind, positions=positions,
-                               cache=lc, enc_out=enc_out, cross_kv=ckv,
-                               impls=impls)
+                               cache=lc, enc_out=enc_out, cross_kv=ckv)
         c = tmap(lambda buf, new: jax.lax.dynamic_update_index_in_dim(
             buf, new.astype(buf.dtype), i, 0), c, nc)
         return (y, aux + a, c, i + 1), None
@@ -299,8 +275,7 @@ def apply_segment(params, x, cfg, seg: Segment, *, positions, cache=None,
     (x, aux, new_cache, _), _ = jax.lax.scan(
         step_cached,
         (x, jnp.zeros((), jnp.float32), cache, jnp.zeros((), jnp.int32)),
-        (params, cross_kv),
-        unroll=seg.count if unroll else 1)
+        (params, cross_kv))
     return x, new_cache, aux
 
 
@@ -360,19 +335,19 @@ def embed_tokens(params, tokens, cfg, positions=None, dtype=jnp.bfloat16):
     return sharding.shard_act(h, ("batch", None, None))
 
 
-def run_encoder(params, frame_embeds, cfg, impls=None, remat=True):
+def run_encoder(params, frame_embeds, cfg, remat=True):
     """Whisper encoder over precomputed (stub) frame embeddings."""
     enc = params["encoder"]
     h = frame_embeds + enc["pos"].astype(frame_embeds.dtype)[None]
     positions = layers.positions_from_shape(h.shape[0], h.shape[1])
     for seg_params, seg in zip(enc["segments"], encoder_segments(cfg)):
         h, _, _ = apply_segment(seg_params, h, cfg, seg, positions=positions,
-                                impls=impls, remat=remat)
+                                remat=remat)
     return layers.apply_norm(h, enc["norm"], cfg.norm)
 
 
 def forward_body(params, h, cfg, *, positions, cache=None, enc_out=None,
-                 cross_kv=None, impls=None, remat=True):
+                 cross_kv=None, remat=True):
     """Embeddings -> final hidden states. Returns (h, new_caches, aux).
 
     Meta tokens are prepended by the MPSL LM loss only."""
@@ -386,7 +361,7 @@ def forward_body(params, h, cfg, *, positions, cache=None, enc_out=None,
         seg_ckv = cross_kv[i] if cross_kv is not None else None
         h, nc, aux = apply_segment(
             seg_params, h, cfg, seg, positions=positions, cache=seg_cache,
-            enc_out=enc_out, cross_kv=seg_ckv, impls=impls, remat=remat)
+            enc_out=enc_out, cross_kv=seg_ckv, remat=remat)
         if new_caches is not None:
             new_caches.append(nc)
         aux_total = aux_total + aux

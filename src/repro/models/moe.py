@@ -1,13 +1,15 @@
 """Mixture-of-Experts FFN (Qwen-MoE family): routed top-k experts with an
 optional always-on shared expert, plus a load-balance auxiliary loss.
 
-Two interchangeable dispatch implementations:
+Three dispatch implementations; the model runs `dense`, and only tests
+select the others:
   * dense  — every expert processes every token, combine weights zero out
              non-selected experts. Exact, partitioner-trivial, O(E/topk)
-             FLOPs overhead; used for smoke tests and as the oracle.
+             FLOPs overhead; also the oracle.
   * ragged — tokens sorted by expert, jax.lax.ragged_dot group matmuls;
-             FLOPs proportional to activated experts only. The production
-             path (beyond-paper optimization for the MoE dry-run cells).
+             FLOPs proportional to activated experts only.
+  * ep     — experts sharded over the 'model' axis under shard_map, tokens
+             past a per-expert capacity dropped.
 """
 from __future__ import annotations
 
@@ -56,12 +58,6 @@ def _routing(params, x, cfg):
     density_proxy = jnp.mean(probs, axis=0)
     aux = m.num_experts * jnp.sum(density * density_proxy) * m.router_aux_coef
     return weights.astype(x.dtype), idx, aux
-
-
-def _expert_ffn(h_in, gate_in, wo, activation):
-    act = layers.act_fn(activation)
-    h = act(gate_in) * h_in if gate_in is not None else act(h_in)
-    return h, wo
 
 
 def _apply_dense(params, x, cfg, weights, idx):

@@ -1,6 +1,4 @@
-"""Optimizers: AdamW with trainable-subtree masking, schedules, clipping,
-microbatch gradient accumulation."""
+"""Optimizers: AdamW with trainable-subtree masking, schedules, clipping."""
 from repro.optim.adamw import (adamw_init, adamw_update, apply_updates,
                                global_norm, clip_by_global_norm)
 from repro.optim.schedules import warmup_cosine, constant
-from repro.optim.accum import accumulate_grads

@@ -33,9 +33,6 @@ LOGICAL = {
     "model": ("model",),
     "dboth": ("data", "model"),
     "pod": ("pod",),
-    # sequence parallelism: residual-stream activations sharded on seq over
-    # the TP axis (gathered at matmul regions by the partitioner)
-    "seq_model": ("model",),
 }
 
 _state = threading.local()
@@ -291,13 +288,3 @@ def cache_dims(shape: Tuple[int, ...], leaf: str, stacked: bool,
     if leaf == "conv" and n == 3:             # [B, dc-1, di]
         return (None,) * len(lead) + ("batch", None, "model")
     return tuple(None for _ in shape)
-
-
-def cache_specs(cache, mesh: Mesh, stacked: bool = True):
-    def rule(key_path, leaf):
-        path = _path_names(key_path)
-        shape = tuple(getattr(leaf, "shape", ()))
-        with use_mesh(mesh):
-            return resolve_spec(mesh, shape,
-                                cache_dims(shape, path[-1], stacked))
-    return jax.tree_util.tree_map_with_path(rule, cache)

@@ -3,7 +3,7 @@ import os
 import random
 import sys
 
-# Tests run on the single host device (the dry-run alone forces 512).
+# Tests run on the single host device.
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import jax

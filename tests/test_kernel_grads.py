@@ -416,8 +416,8 @@ def test_fused_ce_matches_ref_vjp(t, d, v, bt, bv):
 
 @pytest.mark.slow
 def test_chunked_ce_pallas_impl_matches_jnp_impl():
-    """The run.impls-selected kernel path == the checkpointed jnp oracle,
-    value and gradient, with a validity mask."""
+    """The fused CE kernel path (impl="pallas") == the checkpointed jnp
+    oracle, value and gradient, with a validity mask."""
     key = jax.random.PRNGKey(31)
     t, d, v = 90, 32, 250
     h = jax.random.normal(key, (t, d)) * 0.5

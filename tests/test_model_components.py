@@ -272,11 +272,12 @@ def test_tokenizers_shapes_and_cls():
 
 def test_moe_ep_equals_dense_on_mesh():
     """Expert-parallel shard_map dispatch == dense dispatch, on a real
-    (data, model) device mesh (the production MoE path)."""
+    (data, model) device mesh."""
     import os
     import jax as _jax
     if len(_jax.devices()) < 2:
-        pytest.skip("needs >1 host device (run via dryrun/roofline paths)")
+        pytest.skip("needs >1 host device (XLA_FLAGS="
+                    "--xla_force_host_platform_device_count=4)")
     from repro.parallel import sharding as sh
     cfg = dataclasses.replace(
         reduced(get_config("qwen2-moe-a2.7b")),

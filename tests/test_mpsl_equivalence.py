@@ -129,7 +129,7 @@ def test_microbatching_preserves_gradients(mu):
     run = RunConfig(model=cfg, shape=SHAPES["train_4k"],
                     mpsl=MPSLConfig(n_clients=n, trainable_blocks=1,
                                     head_adapter_rank=4),
-                    compute_dtype="float32", microbatches=mu)
+                    compute_dtype="float32")
     state = mpsl.init_state(params, frozen)
     step = jax.jit(mpsl.make_train_step(loss_fn, run,
                                         schedules.constant(0.0),

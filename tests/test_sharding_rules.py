@@ -61,7 +61,7 @@ def test_client_params_shard_on_client_axis():
 
 def test_full_arch_sweep_specs_valid():
     """Every assigned arch's full-size param tree resolves to legal specs
-    (all sharded dims divisible) on both production meshes."""
+    (all sharded dims divisible) on a 16x16 and a 2x16x16 mesh."""
     from repro.configs import ASSIGNED_ARCHS
     for mesh in (MESH, MESH3):
         sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))

@@ -1,7 +1,7 @@
 """Per-architecture smoke tests (deliverable f): a REDUCED same-family
 config runs one forward and one MPSL train step on CPU with finite
-outputs and the right shapes. Full configs are exercised only via the
-dry-run.
+outputs and the right shapes. Full configs run on the chip (`chipbench/`,
+`chip_smoke.py`); tests/test_sharding_rules.py resolves their shardings.
 
 Tiering: tier-1 keeps one representative arch per code path (dense /
 ssm / encoder-decoder); the full per-arch sweep and the decode-vs-full

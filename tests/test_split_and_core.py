@@ -61,8 +61,7 @@ def test_assemble_full_params_matches_split_forward(arch, tb):
     if cfg.encoder_layers:
         fe = jnp.zeros((b, cfg.encoder_seq, cfg.d_model))
         enc2 = M.run_encoder(frozen, fe, cfg, remat=False)
-    hh2, _ = _run_body(frozen, params["server"], cfg, h2, pos, {}, False,
-                       enc_out=enc2)
+    hh2, _ = _run_body(frozen, params["server"], cfg, h2, pos, enc_out=enc2)
     l_split = M.lm_logits(params["server"], hh2, cfg) \
         if "lm_head" in params["server"] else M.lm_logits(frozen, hh2, cfg)
     np.testing.assert_allclose(np.asarray(l_full), np.asarray(l_split),
